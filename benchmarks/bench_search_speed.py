@@ -702,10 +702,17 @@ def ci_smoke_baseline(n_runs: int = 3) -> dict:
     per-query dispatch perturbation on shared CPU hosts is one-sided (the
     flex path only ever loses ground to the batched path, 2x swings
     observed), so the lowest ratio is the least-perturbed, most
-    normalization-faithful baseline."""
+    normalization-faithful baseline.
+
+    CPU only: a parent that has touched JAX on a TPU host holds the chip,
+    and the children would then fail or hang waiting for it."""
     import os
     import subprocess
     import sys
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError("ci_smoke_baseline starts child interpreters and "
+                           "cannot run from a process that holds the TPU")
     samples = []
     for _ in range(n_runs):
         proc = subprocess.run(

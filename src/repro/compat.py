@@ -1,49 +1,16 @@
-"""jax version-compat shims.
+"""The mesh and shard_map surface the repo builds on (jax >= 0.9).
 
-The codebase targets the public jax >= 0.6 surface (`jax.shard_map`,
-`jax.make_mesh(..., axis_types=...)`); this container ships jax 0.4.x where
-`shard_map` still lives in `jax.experimental.shard_map` (with the replication
-check spelled `check_rep` instead of `check_vma`) and `make_mesh` does not
-take `axis_types`.  Everything in-repo imports through here so both surfaces
-work unchanged.
+`shard_map` and `make_mesh` are jax's own; `auto_axis_types` spells the
+fully-automatic axis types every mesh here uses.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax import make_mesh, shard_map
 
-try:                                   # jax >= 0.6: public API
-    from jax import shard_map as _shard_map
-except ImportError:                    # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = set(inspect.signature(_shard_map).parameters)
+__all__ = ["auto_axis_types", "make_mesh", "shard_map"]
 
 
-def shard_map(f, *args, **kwargs):
-    """`jax.shard_map` with `check_vma`/`check_rep` translated as needed."""
-    if "check_vma" in kwargs and "check_vma" not in _SHARD_MAP_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SHARD_MAP_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(f, *args, **kwargs)
-
-
-_MAKE_MESH_PARAMS = set(inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(axis_shapes, axis_names, **kwargs):
-    """`jax.make_mesh`, dropping `axis_types` on jax versions without it
-    (pre-AxisType meshes behave as fully-auto, which is what we pass)."""
-    if "axis_types" in kwargs and "axis_types" not in _MAKE_MESH_PARAMS:
-        kwargs.pop("axis_types")
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def auto_axis_types(n: int):
-    """(AxisType.Auto,) * n where AxisType exists, else None (old jax)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return None
-    return (axis_type.Auto,) * n
+def auto_axis_types(n: int) -> tuple:
+    """(AxisType.Auto,) * n — the axis types of every mesh in the repo."""
+    return (jax.sharding.AxisType.Auto,) * n

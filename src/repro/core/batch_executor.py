@@ -75,7 +75,8 @@ from repro.core.postings import (BLOCK, PHRASE_BIAS, POS_BITS, concat_packed,
                                  pad_block_multiple)
 from repro.kernels.ops import (I32_SENTINEL, banded_delta_mask_rows,
                                banded_intersect_rows, banded_min_delta_rows,
-                               kword_window_hits, unpack_postings)
+                               kword_window_hits, resolve_kernels,
+                               unpack_postings)
 
 # table caps: a task exceeding these routes its whole plan to the flexible
 # executor (rare: >8 AND-groups or >8 unioned form fetches per slot).
@@ -468,14 +469,15 @@ class BatchExecutor:
     of the doc-shard count (segmented rows)."""
 
     def __init__(self, index: IndexSet, flex: Executor | None = None,
-                 impl: str = "ref", interpret: bool = True,
+                 impl: str | None = None, interpret: bool | None = None,
                  docs_per_shard: int | None = None, doc_base: int = 0):
         self.index = index
         self.dev = BatchDeviceIndex(index, docs_per_shard=docs_per_shard,
                                     doc_base=doc_base)
         self.flex = flex or Executor(index)
-        self.impl = impl
-        self.interpret = interpret
+        # None = the platform's choice (Pallas, compiled, on a TPU; the jnp
+        # reference elsewhere); "ref" stays selectable explicitly
+        self.impl, self.interpret = resolve_kernels(impl, interpret)
         # packed-key safety: positions (plus bias, the widest dist shift,
         # and the widest band) must fit the 17-bit in-doc field or
         # cross-doc false positives appear
@@ -711,7 +713,9 @@ class BatchExecutor:
             for ti, row_scores in enumerate(np.split(svals, splits)):
                 part[ti].scores = row_scores
 
-    def _run_rows(self, rows: list):
+    def _bucket_chunks(self, rows: list):
+        """Yield (rows, device tables, static step kwargs), one per jit'd
+        bucket-step call over `rows`."""
         buckets: dict = {}
         for row in rows:
             buckets.setdefault(self._bucket_key(row), []).append(row)
@@ -734,19 +738,43 @@ class BatchExecutor:
                 # cost at smoke scale)
                 tj = {k: jnp.asarray(v) for k, v in t.items()
                       if ranked or k not in ("score_bias", "score_from_dist")}
-                out = _batch_step(
-                    d.device_arena, tj,
-                    P0=P0, P=P, impl=self.impl, interpret=self.interpret,
-                    presorted=sortfree, ranked=ranked, kword=kword)
-                if ranked:
-                    a64, found, scores = out
-                    self._scatter_row_keys(part, np.asarray(a64),
-                                           np.asarray(found),
-                                           np.asarray(scores))
-                else:
-                    a64, found = out
-                    self._scatter_row_keys(part, np.asarray(a64),
-                                           np.asarray(found))
+                yield part, tj, dict(P0=P0, P=P, impl=self.impl,
+                                     interpret=self.interpret,
+                                     presorted=sortfree, ranked=ranked,
+                                     kword=kword)
+
+    def _run_rows(self, rows: list):
+        for part, tj, static in self._bucket_chunks(rows):
+            out = _batch_step(self.dev.device_arena, tj, **static)
+            if static["ranked"]:
+                a64, found, scores = out
+                self._scatter_row_keys(part, np.asarray(a64),
+                                       np.asarray(found), np.asarray(scores))
+            else:
+                a64, found = out
+                self._scatter_row_keys(part, np.asarray(a64),
+                                       np.asarray(found))
+
+    def lower_steps(self, plans: list[QueryPlan],
+                    requests: list[SearchRequest]) -> dict:
+        """The bucket-step calls that the main round of
+        `execute_batch(plans, requests=requests)` makes, lowered
+        (`jax.stages.Lowered`) and keyed by their static arguments and table
+        shapes.  Compiling one fills the same cache the call would, so a
+        cold start can compile them concurrently; the compiled text shows
+        which kernels a served step runs."""
+        tasks: list[_Task] = []
+        for i, (plan, req) in enumerate(zip(plans, requests)):
+            self._build_tasks(i, plan, tasks, ranked=req.rank)
+        rows = [r for t in tasks if not t.fallback for r in t.rows]
+        out = {}
+        for _, tj, static in self._bucket_chunks(rows):
+            key = (tuple(sorted(static.items())),
+                   tuple(sorted((k, v.shape) for k, v in tj.items())))
+            if key not in out:
+                out[key] = _batch_step.lower(self.dev.device_arena, tj,
+                                             **static)
+        return out
 
     # -- merge (mirrors Executor.execute) -----------------------------------
 

@@ -52,7 +52,8 @@ class _BatchSearchMixin:
     """Shared lazy batch-executor plumbing: the batched arena duplicates the
     posting streams on device, so per-query-only users never pay for it."""
 
-    def _init_batch(self, batch_impl: str, interpret: bool,
+    def _init_batch(self, batch_impl: str | None,
+                    interpret: bool | None,
                     docs_per_shard: int | None = None, doc_base: int = 0):
         self._batch_impl = batch_impl
         self._interpret = interpret
@@ -110,8 +111,9 @@ class AdditionalIndexEngine(_BatchSearchMixin):
     `SearchResponse`s; `rank=True` requests carry proximity-ranked DocHits.
     """
 
-    def __init__(self, index: IndexSet, batch_impl: str = "ref",
-                 interpret: bool = True, docs_per_shard: int | None = None,
+    def __init__(self, index: IndexSet, batch_impl: str | None = None,
+                 interpret: bool | None = None,
+                 docs_per_shard: int | None = None,
                  windowed_near_stop: bool = True, occ_counts=None,
                  doc_base: int = 0):
         self.index = index
@@ -144,8 +146,9 @@ class AdditionalIndexEngine(_BatchSearchMixin):
 class OrdinaryEngine(_BatchSearchMixin):
     """Sphinx-style baseline: one inverted index, full posting-list reads."""
 
-    def __init__(self, index: IndexSet, batch_impl: str = "ref",
-                 interpret: bool = True, docs_per_shard: int | None = None):
+    def __init__(self, index: IndexSet, batch_impl: str | None = None,
+                 interpret: bool | None = None,
+                 docs_per_shard: int | None = None):
         self.index = index
         self.executor = Executor(index)
         self._init_batch(batch_impl, interpret, docs_per_shard)

@@ -136,7 +136,8 @@ class SegmentManager:
 
     def __init__(self, lexicon, analyzer, params: IndexParams | None = None,
                  *, merge_threshold: int = 4, auto_merge: bool = True,
-                 batch_impl: str = "ref", interpret: bool = True):
+                 batch_impl: str | None = None,
+                 interpret: bool | None = None):
         self.lexicon = lexicon
         self.analyzer = analyzer
         self.params = params if params is not None else IndexParams()

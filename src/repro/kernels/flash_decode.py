@@ -66,7 +66,7 @@ def _kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def flash_decode_pallas(q4: jax.Array, k: jax.Array, v: jax.Array,
                         kv_len: jax.Array, *, block_s: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q4: [B, Hkv, G, D]; k, v: [B, S, Hkv, D]; kv_len: [B] int32.
 
     Returns [B, Hkv, G, D] in q4.dtype.  S must be a multiple of block_s.

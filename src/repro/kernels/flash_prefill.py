@@ -69,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def flash_prefill_pallas(q5: jax.Array, k: jax.Array, v: jax.Array, *,
                          block_q: int = 512, block_kv: int = 512,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q5: [B, Hkv, G*S, D] (G query heads per KV head, flattened with S);
     k, v: [B, S, Hkv, D].  Returns [B, Hkv, G*S, D] in q5.dtype.
 

@@ -1,14 +1,14 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Each op takes `implementation='pallas' | 'ref'` (+ `interpret=` for the
-pallas path; on this CPU container interpret=True is the default and the
-TPU-lowering path is exercised by the dry-run).  Tests sweep shapes/dtypes
-and assert the two implementations agree exactly (integer ops) or to bf16
-tolerance (attention).
+Each op takes `implementation='pallas' | 'ref'` and, for the pallas path,
+`interpret=`.  Which of them a caller gets by default is decided here, from
+the platform, and nowhere else (`resolve_kernels`): on a
+TPU the served path runs the compiled Pallas kernels; elsewhere it runs the
+jnp reference, and Pallas calls run in interpret mode (the tests' parity
+oracle).  Tests sweep shapes/dtypes and assert the two implementations agree
+exactly (integer ops) or to bf16 tolerance (attention).
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,12 +16,12 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.flash_decode import flash_decode_pallas
 from repro.kernels.flash_prefill import flash_prefill_pallas
-from repro.kernels.intersect import (I32_SENTINEL, banded_delta_mask_rows_pallas,
-                                     banded_intersect_pallas,
+from repro.kernels.intersect import (I32_SENTINEL, TILE,
+                                     banded_delta_mask_rows_pallas,
                                      banded_intersect_rows_pallas,
                                      banded_min_delta_rows_pallas)
 from repro.kernels.segment_bag import segment_bag_pallas
-from repro.kernels.unpack import ROWS_PER_TILE, unpack_fields_pallas
+from repro.kernels.unpack import unpack_fields_pallas
 
 _SDB = 4      # delta bits of the (key << 4 | delta) scoring composite
               # (== core.fetch_tables.SCORE_DELTA_BITS; kept literal here so
@@ -34,13 +34,30 @@ _BLOCK = 1 << _BLOCK_LOG2
 _WBITS = 6
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _interp(interpret: bool | None) -> bool:
+    """Pallas interpret mode: None = off on a TPU, on everywhere else."""
+    return not _on_tpu() if interpret is None else interpret
+
+
+def resolve_kernels(impl: str | None,
+                    interpret: bool | None) -> tuple[str, bool]:
+    """(impl, interpret) with each None filled in from the platform: the
+    compiled Pallas kernels on a TPU, the jnp 'ref' path elsewhere.  'ref'
+    stays selectable by explicit argument."""
+    return impl or ("pallas" if _on_tpu() else "ref"), _interp(interpret)
+
+
 # ---------------------------------------------------------------------------
 # packed-postings unpack
 # ---------------------------------------------------------------------------
 
 def unpack_fields(words: jax.Array, shifts: jax.Array, widths: jax.Array,
                   anchors: jax.Array, *, implementation: str = "pallas",
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """anchor + ((word >> shift) & mask(width)) elementwise — the bit-extract
     half of the packed-postings decode (any int32 shape; the Pallas path
     pads/reshapes to [R, 128] tiles)."""
@@ -48,39 +65,33 @@ def unpack_fields(words: jax.Array, shifts: jax.Array, widths: jax.Array,
         mask = jnp.where(widths >= 32, jnp.int32(-1),
                          (jnp.int32(1) << jnp.minimum(widths, 31)) - 1)
         return anchors + ((words >> shifts) & mask)
-    shape = words.shape
-    n = words.size
-    tile = ROWS_PER_TILE * 128
-    pad = (-n) % tile
-
-    def prep(x):
-        x = x.reshape(-1)
-        if pad:
-            x = jnp.concatenate([x, jnp.zeros((pad,), jnp.int32)])
-        return x.reshape(-1, 128)
-
-    out = unpack_fields_pallas(prep(words), prep(shifts), prep(widths),
-                               prep(anchors), interpret=interpret)
-    return out.reshape(-1)[:n].reshape(shape)
+    out = unpack_fields_pallas(words.reshape(-1), shifts.reshape(-1),
+                               widths.reshape(-1), anchors.reshape(-1),
+                               interpret=_interp(interpret))
+    return out.reshape(words.shape)
 
 
 def unpack_postings(arena: dict, idx: jax.Array, *,
-                    implementation: str = "ref", interpret: bool = True):
+                    implementation: str = "ref",
+                    interpret: bool | None = None):
     """(doc, pos, dist) int32 for posting ordinals `idx` of a packed arena.
 
     arena: device dict with `lanes` [W] int32 packed delta words and
     `blk_meta` [NB, 5] int32 per-block metadata (column 0 = base lane word,
     1 = packed field widths, 2..4 = doc/pos/dist anchors — see
     core.postings.PackedPostings.meta_matrix; NB * 128 is the addressable
-    ordinal range).  One metadata row gather + one lane gather per field are
+    ordinal range).  The metadata gathers and one lane gather per field are
     plain XLA gathers; the bit extract runs through `unpack_fields` (ref
     math or the Pallas kernel).  Out-of-range lane reads (width-0 tail
     blocks) rely on jnp's clamping gather semantics."""
     lanes = arena["lanes"]
     blk = idx >> _BLOCK_LOG2
     off = idx & (_BLOCK - 1)
-    meta = arena["blk_meta"][blk]              # [..., 5] one gather
-    base, bw = meta[..., 0], meta[..., 1]
+    # one 1-D gather per metadata column: a row gather from the narrow
+    # [NB, 5] matrix makes the chip's compiler plan temporaries of ~1 KB
+    # per index at some NB (15 GB for a 15M-posting bucket)
+    meta = [arena["blk_meta"][:, c][blk] for c in range(5)]
+    base, bw = meta[0], meta[1]
     m = (1 << _WBITS) - 1
     ws = [bw & m, (bw >> _WBITS) & m, (bw >> (2 * _WBITS)) & m]
     fbs = [base, base + (ws[0] << 2), base + ((ws[0] + ws[1]) << 2)]
@@ -90,87 +101,119 @@ def unpack_postings(arena: dict, idx: jax.Array, *,
         words.append(lanes[fb + (bit >> 5)])
         shifts.append(bit & 31)
     out = unpack_fields(jnp.stack(words), jnp.stack(shifts), jnp.stack(ws),
-                        jnp.stack([meta[..., 2], meta[..., 3], meta[..., 4]]),
+                        jnp.stack(meta[2:]),
                         implementation=implementation, interpret=interpret)
     return out[0], out[1], out[2]
-
-
-def _pad_to(x: jax.Array, mult: int, fill) -> jax.Array:
-    n = x.shape[0]
-    pad = (-n) % mult
-    if pad == 0:
-        return x
-    return jnp.concatenate([x, jnp.full((pad,) + x.shape[1:], fill, x.dtype)])
 
 
 # ---------------------------------------------------------------------------
 # banded intersection
 # ---------------------------------------------------------------------------
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _row_block(p: int, req: int) -> tuple[int, int]:
+    """(padded row width, block) for a row of width p: rows pad to whole
+    (8, 128) tiles, and the block is the largest multiple of TILE that is at
+    most max(req, TILE) and divides the padded width (blocks never straddle
+    logical rows)."""
+    p = _round_up(p, TILE)
+    blk = max(req // TILE, 1) * TILE
+    while p % blk:
+        blk -= TILE
+    return p, blk
+
+
+def _pad_row(x: jax.Array, width: int, fill) -> jax.Array:
+    pad = width - x.shape[1]
+    return jnp.pad(x, ((0, 0), (0, pad)), constant_values=fill) if pad else x
+
+
+def _banded_rows(kernel, a, b_planes, bands, block_a, block_b, interpret):
+    """Shared host side of the three banded row kernels.  a: [N, Pa] int32;
+    b_planes: aligned [N, Pb] int32 planes, the first one (the keys) sorted
+    per row.  Pads rows to whole tiles (a and keys with I32_SENTINEL, other
+    planes with 0), finds per a-block the band-overlapping run of b blocks
+    from the block minima, and runs `kernel` over it.  Sentinel a entries
+    are left out of the block ranges: their output is never read."""
+    N, pa = a.shape
+    pa_pad, block_a = _row_block(pa, block_a)
+    pb_pad, block_b = _row_block(b_planes[0].shape[1], block_b)
+    a = _pad_row(a, pa_pad, I32_SENTINEL)
+    b_planes = [_pad_row(x, pb_pad, I32_SENTINEL if i == 0 else 0)
+                for i, x in enumerate(b_planes)]
+    nab_pp = pa_pad // block_a            # a-blocks per row
+    nbb_pp = pb_pad // block_b            # b-blocks per row
+
+    # per-a-block value range over real keys (int64: +/- band must not wrap)
+    a_t = a.reshape(N, nab_pp, block_a).astype(jnp.int64)
+    real = a_t != I32_SENTINEL
+    amin = jnp.where(real, a_t, I32_SENTINEL).min(axis=2)
+    amax = jnp.where(real, a_t, -1).max(axis=2)
+    b_block_min = b_planes[0].reshape(N, nbb_pp, block_b)[:, :, 0] \
+        .astype(jnp.int64)
+    band64 = bands.astype(jnp.int64)[:, None]
+    # side='left' - 1: a block whose min equals amin-band may be preceded by
+    # a block ending in the same value (duplicates straddling the boundary);
+    # clip keeps the range inside the owning row
+    lo = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="left"))(
+        b_block_min, amin - band64)
+    lo = jnp.clip(lo - 1, 0, nbb_pp - 1)
+    hi = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="right"))(
+        b_block_min, amax + band64)
+    n_tiles = jnp.where(real.any(axis=2), jnp.maximum(hi - lo, 0), 0) \
+        .astype(jnp.int32)
+    # absolute b-block index: offset into the row's own b segment
+    row_base = (jnp.arange(N, dtype=jnp.int64) * nbb_pp)[:, None]
+    lo_abs = (lo + row_base).astype(jnp.int32)
+    band_per_block = jnp.broadcast_to(bands.astype(jnp.int32)[:, None],
+                                      (N, nab_pp))
+    out2d = kernel(
+        a.reshape(-1, 128), *(x.reshape(-1, 128) for x in b_planes),
+        lo_abs.reshape(-1), n_tiles.reshape(-1), band_per_block.reshape(-1),
+        block_a=block_a, block_b=block_b, max_tiles=nbb_pp,
+        interpret=_interp(interpret))
+    return out2d.reshape(N, pa_pad)[:, :pa]
+
+
 def banded_intersect(a: jax.Array, b_sorted: jax.Array, band: int, *,
-                     implementation: str = "pallas", interpret: bool = True,
-                     block_a: int = 1024, block_b: int = 1024,
-                     max_tiles: int | None = None) -> jax.Array:
+                     implementation: str = "pallas",
+                     interpret: bool | None = None, block_a: int = TILE,
+                     block_b: int = TILE) -> jax.Array:
     """found[i] = exists j with |a[i] - b_sorted[j]| <= band.
 
     a: [Na] int32 (any order); b_sorted: [Nb] int32 ascending.  Returns
-    bool [Na].  Entries equal to I32_SENTINEL never match (padding).
+    bool [Na].  Entries equal to I32_SENTINEL never match (padding).  The
+    Pallas path is the one-row case of `banded_intersect_rows`.
     """
     assert a.dtype == jnp.int32 and b_sorted.dtype == jnp.int32
     if implementation == "ref":
         found = ref.banded_intersect_ref(a, b_sorted, band)
         return found & (a != I32_SENTINEL)
-
-    na, nb = a.shape[0], b_sorted.shape[0]
-    if na == 0 or nb == 0:
-        return jnp.zeros((na,), jnp.bool_)
-    a_pad = _pad_to(a, block_a, I32_SENTINEL)
-    b_pad = _pad_to(b_sorted, block_b, I32_SENTINEL)
-    nab = a_pad.shape[0] // block_a
-    nbb = b_pad.shape[0] // block_b
-
-    a_tiles = a_pad.reshape(nab, block_a)
-    # int64 bounds: sentinel +/- band must not wrap (keys are < 2**30)
-    amin = a_tiles.min(axis=1).astype(jnp.int64)
-    amax = a_tiles.max(axis=1).astype(jnp.int64)
-    b_block_min = b_pad.reshape(nbb, block_b)[:, 0].astype(jnp.int64)
-    # side='left': a block whose min equals amin-band may be preceded by a
-    # block ending in the same value (duplicates straddling the boundary)
-    lo = jnp.clip(jnp.searchsorted(b_block_min, amin - band, side="left") - 1, 0, nbb - 1)
-    hi = jnp.searchsorted(b_block_min, amax + band, side="right")
-    n_tiles = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-    lo = lo.astype(jnp.int32)
-
-    if max_tiles is None:
-        if isinstance(n_tiles, jax.core.Tracer):
-            max_tiles = nbb                         # static worst case under jit
-        else:
-            max_tiles = max(int(n_tiles.max()), 1)
-    max_tiles = max(min(max_tiles, nbb), 1)
-
-    out2d = banded_intersect_pallas(
-        a_pad.reshape(-1, 128), b_pad.reshape(-1, 128), lo, n_tiles,
-        band=band, block_a=block_a, block_b=block_b, max_tiles=max_tiles,
-        interpret=interpret)
-    found = out2d.reshape(-1)[:na] > 0
-    return found & (a != I32_SENTINEL)
+    if a.shape[0] == 0 or b_sorted.shape[0] == 0:
+        return jnp.zeros(a.shape, jnp.bool_)
+    return banded_intersect_rows(
+        a[None], b_sorted[None], jnp.full((1,), band, jnp.int32),
+        interpret=interpret, block_a=block_a, block_b=block_b)[0]
 
 
 def banded_intersect_rows(a: jax.Array, b_sorted: jax.Array, bands: jax.Array,
                           *, implementation: str = "pallas",
-                          interpret: bool = True, block_a: int = 1024,
-                          block_b: int = 1024) -> jax.Array:
+                          interpret: bool | None = None, block_a: int = TILE,
+                          block_b: int = TILE) -> jax.Array:
     """Batched banded membership: found[n, i] = exists j with
     |a[n, i] - b_sorted[n, j]| <= bands[n].
 
     a: [N, Pa] int32 (any order); b_sorted: [N, Pb] int32, ascending per row;
     bands: [N] int32 (DYNAMIC — one pallas program serves mixed band widths
     via scalar prefetch, so the batch executor never recompiles per band
-    pattern).  Pa/Pb must be multiples of 128.  I32_SENTINEL entries of `a`
-    never match.  This is the engine hot path: each row is one (seed group,
-    constraint group) membership test of a shard-segmented batch-executor
-    row — the same call the serve tier runs inside shard_map, where every
-    logical row's keys are re-based against its own doc shard.
+    pattern).  I32_SENTINEL entries of `a` never match.  This is the engine
+    hot path: each row is one (seed group, constraint group) membership test
+    of a shard-segmented batch-executor row — the same call the serve tier
+    runs inside shard_map, where every logical row's keys are re-based
+    against its own doc shard.
     """
     assert a.dtype == jnp.int32 and b_sorted.dtype == jnp.int32
     N, pa = a.shape
@@ -185,47 +228,9 @@ def banded_intersect_rows(a: jax.Array, b_sorted: jax.Array, bands: jax.Array,
 
     if N == 0 or pa == 0 or pb == 0:
         return jnp.zeros((N, pa), jnp.bool_)
-
-    def pick_block(p, req):
-        # largest multiple of 128 that divides the row width (tiles must not
-        # straddle rows: each logical row owns whole blocks)
-        for blk in range(max(min(req, p) // 128 * 128, 128), 127, -128):
-            if p % blk == 0:
-                return blk
-        raise ValueError(f"row width {p} not a multiple of 128")
-
-    block_a = pick_block(pa, block_a)
-    block_b = pick_block(pb, block_b)
-    nab_pp = pa // block_a            # a-blocks per row
-    nbb_pp = pb // block_b            # b-blocks per row
-
-    # per-a-block value range (int64: sentinel +/- band must not wrap)
-    a_t = a.reshape(N, nab_pp, block_a)
-    amin = a_t.min(axis=2).astype(jnp.int64)           # [N, nab_pp]
-    amax = a_t.max(axis=2).astype(jnp.int64)
-    b_block_min = b_sorted.reshape(N, nbb_pp, block_b)[:, :, 0].astype(jnp.int64)
-    band64 = bands.astype(jnp.int64)[:, None]
-    # side='left' - 1: duplicates straddling a block boundary (see
-    # banded_intersect); clip keeps the range inside the owning row
-    lo = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="left"))(
-        b_block_min, amin - band64)
-    lo = jnp.clip(lo - 1, 0, nbb_pp - 1)
-    hi = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="right"))(
-        b_block_min, amax + band64)
-    n_tiles = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-    # absolute b-block index: offset into the row's own b segment
-    row_base = (jnp.arange(N, dtype=jnp.int64) * nbb_pp)[:, None]
-    lo_abs = (lo + row_base).astype(jnp.int32)
-    band_per_block = jnp.broadcast_to(bands.astype(jnp.int32)[:, None],
-                                      (N, nab_pp))
-
-    out2d = banded_intersect_rows_pallas(
-        a.reshape(-1, 128), b_sorted.reshape(-1, 128),
-        lo_abs.reshape(-1), n_tiles.reshape(-1), band_per_block.reshape(-1),
-        block_a=block_a, block_b=block_b, max_tiles=nbb_pp,
-        interpret=interpret)
-    found = out2d.reshape(N, pa) > 0
-    return found & (a != I32_SENTINEL)
+    out = _banded_rows(banded_intersect_rows_pallas, a, [b_sorted], bands,
+                       block_a, block_b, interpret)
+    return (out > 0) & (a != I32_SENTINEL)
 
 
 _KW_MAX_BAND = 15   # device kword window cap: bit (d + band) <= 30 per lane
@@ -234,8 +239,8 @@ _KW_MAX_BAND = 15   # device kword window cap: bit (d + band) <= 30 per lane
 def banded_delta_mask_rows(a: jax.Array, b_sorted: jax.Array,
                            bands: jax.Array, *,
                            implementation: str = "pallas",
-                           interpret: bool = True, block_a: int = 1024,
-                           block_b: int = 1024) -> jax.Array:
+                           interpret: bool | None = None, block_a: int = TILE,
+                           block_b: int = TILE) -> jax.Array:
     """Batched signed-delta bitmask (the K-word join twin of
     `banded_intersect_rows`, core/kword.py): out[n, i] has bit
     (d + bands[n]) set iff exists j with b_sorted[n, j] - a[n, i] == d and
@@ -269,39 +274,8 @@ def banded_delta_mask_rows(a: jax.Array, b_sorted: jax.Array,
 
     if N == 0 or pa == 0 or pb == 0:
         return jnp.zeros((N, pa), jnp.int32)
-
-    def pick_block(p, req):
-        for blk in range(max(min(req, p) // 128 * 128, 128), 127, -128):
-            if p % blk == 0:
-                return blk
-        raise ValueError(f"row width {p} not a multiple of 128")
-
-    block_a = pick_block(pa, block_a)
-    block_b = pick_block(pb, block_b)
-    nab_pp = pa // block_a
-    nbb_pp = pb // block_b
-
-    a_t = a.reshape(N, nab_pp, block_a)
-    amin = a_t.min(axis=2).astype(jnp.int64)
-    amax = a_t.max(axis=2).astype(jnp.int64)
-    b_block_min = b_sorted.reshape(N, nbb_pp, block_b)[:, :, 0].astype(jnp.int64)
-    band64 = bands.astype(jnp.int64)[:, None]
-    lo = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="left"))(
-        b_block_min, amin - band64)
-    lo = jnp.clip(lo - 1, 0, nbb_pp - 1)
-    hi = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="right"))(
-        b_block_min, amax + band64)
-    n_tiles = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-    row_base = (jnp.arange(N, dtype=jnp.int64) * nbb_pp)[:, None]
-    lo_abs = (lo + row_base).astype(jnp.int32)
-    band_per_block = jnp.broadcast_to(bands.astype(jnp.int32)[:, None],
-                                      (N, nab_pp))
-    out2d = banded_delta_mask_rows_pallas(
-        a.reshape(-1, 128), b_sorted.reshape(-1, 128),
-        lo_abs.reshape(-1), n_tiles.reshape(-1), band_per_block.reshape(-1),
-        block_a=block_a, block_b=block_b, max_tiles=nbb_pp,
-        interpret=interpret)
-    out = out2d.reshape(N, pa)
+    out = _banded_rows(banded_delta_mask_rows_pallas, a, [b_sorted], bands,
+                       block_a, block_b, interpret)
     return jnp.where(a == I32_SENTINEL, 0, out)
 
 
@@ -344,8 +318,8 @@ def kword_window_hits(masks: jax.Array, active: jax.Array,
 def banded_min_delta_rows(a: jax.Array, b_key_sorted: jax.Array,
                           b_delta: jax.Array, bands: jax.Array, *,
                           implementation: str = "pallas",
-                          interpret: bool = True, block_a: int = 1024,
-                          block_b: int = 1024) -> jax.Array:
+                          interpret: bool | None = None, block_a: int = TILE,
+                          block_b: int = TILE) -> jax.Array:
     """Batched banded min-delta (the proximity-scoring twin of
     `banded_intersect_rows`): out[n, i] = min over j with
     |a[n, i] - b_key[n, j]| <= bands[n] of (|a[n, i] - b_key[n, j]| +
@@ -392,40 +366,9 @@ def banded_min_delta_rows(a: jax.Array, b_key_sorted: jax.Array,
 
     if N == 0 or pa == 0 or pb == 0:
         return jnp.full((N, pa), I32_SENTINEL, jnp.int32)
-
-    def pick_block(p, req):
-        for blk in range(max(min(req, p) // 128 * 128, 128), 127, -128):
-            if p % blk == 0:
-                return blk
-        raise ValueError(f"row width {p} not a multiple of 128")
-
-    block_a = pick_block(pa, block_a)
-    block_b = pick_block(pb, block_b)
-    nab_pp = pa // block_a
-    nbb_pp = pb // block_b
-
-    a_t = a.reshape(N, nab_pp, block_a)
-    amin = a_t.min(axis=2).astype(jnp.int64)
-    amax = a_t.max(axis=2).astype(jnp.int64)
-    b_block_min = b_key_sorted.reshape(N, nbb_pp, block_b)[:, :, 0].astype(jnp.int64)
-    band64 = bands.astype(jnp.int64)[:, None]
-    lo = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="left"))(
-        b_block_min, amin - band64)
-    lo = jnp.clip(lo - 1, 0, nbb_pp - 1)
-    hi = jax.vmap(lambda bm, q: jnp.searchsorted(bm, q, side="right"))(
-        b_block_min, amax + band64)
-    n_tiles = jnp.maximum(hi - lo, 0).astype(jnp.int32)
-    row_base = (jnp.arange(N, dtype=jnp.int64) * nbb_pp)[:, None]
-    lo_abs = (lo + row_base).astype(jnp.int32)
-    band_per_block = jnp.broadcast_to(bands.astype(jnp.int32)[:, None],
-                                      (N, nab_pp))
-    out2d = banded_min_delta_rows_pallas(
-        a.reshape(-1, 128), b_key_sorted.reshape(-1, 128),
-        b_delta.astype(jnp.int32).reshape(-1, 128),
-        lo_abs.reshape(-1), n_tiles.reshape(-1), band_per_block.reshape(-1),
-        block_a=block_a, block_b=block_b, max_tiles=nbb_pp,
-        interpret=interpret)
-    out = out2d.reshape(N, pa)
+    out = _banded_rows(banded_min_delta_rows_pallas, a,
+                       [b_key_sorted, b_delta.astype(jnp.int32)], bands,
+                       block_a, block_b, interpret)
     return jnp.where(a == I32_SENTINEL, I32_SENTINEL, out)
 
 
@@ -435,14 +378,14 @@ def banded_min_delta_rows(a: jax.Array, b_key_sorted: jax.Array,
 
 def segment_bag(table: jax.Array, ids: jax.Array, weights: jax.Array | None = None,
                 combine: str = "sum", *, implementation: str = "pallas",
-                interpret: bool = True) -> jax.Array:
+                interpret: bool | None = None) -> jax.Array:
     """EmbeddingBag(table, ids) -> [B, D]; ids [B, F] int32, -1 = pad."""
     if implementation == "ref":
         return ref.segment_bag_ref(table, ids, weights, combine)
     B, F = ids.shape
     w = weights if weights is not None else jnp.ones((B, F), table.dtype)
     out = segment_bag_pallas(table, ids.astype(jnp.int32), w.astype(table.dtype),
-                             interpret=interpret)       # fp32 accumulator
+                             interpret=_interp(interpret))   # fp32 accumulator
     if combine == "mean":
         denom = jnp.maximum((ids >= 0).sum(axis=1, keepdims=True), 1).astype(jnp.float32)
         out = out / denom
@@ -456,7 +399,7 @@ def segment_bag(table: jax.Array, ids: jax.Array, weights: jax.Array | None = No
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   block_q: int = 512, block_kv: int = 512,
                   implementation: str = "pallas",
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """Causal GQA prefill.  q: [B, S, Hq, D]; k, v: [B, S, Hkv, D].
 
     The Pallas path keeps each (block_q x block_kv) score tile in VMEM
@@ -472,7 +415,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
     q6 = q.reshape(B, S // bq, bq, Hkv, G, D).transpose(0, 3, 1, 4, 2, 5)
     q5 = q6.reshape(B, Hkv, S * G, D)
     out5 = flash_prefill_pallas(q5, k, v, block_q=bq, block_kv=bkv,
-                                interpret=interpret)
+                                interpret=_interp(interpret))
     out = out5.reshape(B, Hkv, S // bq, G, bq, D).transpose(0, 2, 4, 1, 3, 5)
     return out.reshape(B, S, Hq, D)
 
@@ -483,7 +426,8 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  kv_len: jax.Array | int, *, block_s: int = 512,
-                 implementation: str = "pallas", interpret: bool = True) -> jax.Array:
+                 implementation: str = "pallas",
+                 interpret: bool | None = None) -> jax.Array:
     """q: [B, Hq, D]; k, v: [B, S, Hkv, D]; kv_len: [B] or scalar."""
     if implementation == "ref":
         return ref.flash_decode_ref(q, k, v, kv_len)
@@ -500,5 +444,6 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         k = jnp.concatenate([k, zeros], axis=1)
         v = jnp.concatenate([v, zeros], axis=1)
     q4 = q.reshape(B, Hkv, G, D)
-    out = flash_decode_pallas(q4, k, v, kv_len, block_s=bs, interpret=interpret)
+    out = flash_decode_pallas(q4, k, v, kv_len, block_s=bs,
+                              interpret=_interp(interpret))
     return out.reshape(B, Hq, D)

@@ -31,7 +31,7 @@ def _kernel(ids_ref, q_ref, w_ref, o_ref):
 
 
 def segment_bag_pallas(table: jax.Array, ids: jax.Array, weights: jax.Array,
-                       *, interpret: bool = True) -> jax.Array:
+                       *, interpret: bool) -> jax.Array:
     """table: [V, D]; ids: [B, F] int32 (-1 pad); weights: [B, F] table.dtype.
 
     Returns [B, D] weighted bag sums.  Mean combine is applied by the ops.py

@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
-ROWS_PER_TILE = 8
+SUBLANES = 8
+MAX_BLOCK_ROWS = 512         # (512, 128) int32 = 256 KiB per plane
 
 
 def _kernel(words_ref, shift_ref, width_ref, anchor_ref, o_ref):
@@ -38,20 +39,28 @@ def _kernel(words_ref, shift_ref, width_ref, anchor_ref, o_ref):
 
 def unpack_fields_pallas(words: jax.Array, shifts: jax.Array,
                          widths: jax.Array, anchors: jax.Array, *,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """anchor + ((words >> shifts) & mask(widths)), elementwise int32.
 
-    All inputs [R, 128] int32 with R a multiple of ROWS_PER_TILE (ops.py
-    pads); widths in core.postings.PACK_WIDTHS."""
-    R = words.shape[0]
-    grid = (R // ROWS_PER_TILE,)
-    spec = pl.BlockSpec((ROWS_PER_TILE, LANES), lambda i: (i, 0))
+    All inputs [n] int32; widths in core.postings.PACK_WIDTHS.  The planes
+    are padded to whole (rows, 128) blocks, rows a multiple of 8."""
+    n = words.shape[0]
+    rows = -(-max(n, 1) // LANES)
+    rows = -(-rows // SUBLANES) * SUBLANES
+    block_rows = min(rows, MAX_BLOCK_ROWS)
+    rows = -(-rows // block_rows) * block_rows
+
+    def prep(x):
+        return jnp.pad(x, (0, rows * LANES - n)).reshape(rows, LANES)
+
+    spec = pl.BlockSpec((block_rows, LANES), lambda i: (i, jnp.int32(0)))
     fn = pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(rows // block_rows,),
         in_specs=[spec, spec, spec, spec],
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(words.shape, jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret,
     )
-    return fn(words, shifts, widths, anchors)
+    out = fn(prep(words), prep(shifts), prep(widths), prep(anchors))
+    return out.reshape(-1)[:n]

@@ -28,6 +28,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
+
+
+def _device_label() -> str:
+    """platform/device_kind x count, as JAX reports the devices."""
+    d = jax.devices()
+    return f"{d[0].platform}/{d[0].device_kind} x{len(d)}"
 
 
 def _search_world(n_queries: int, ranked: bool, top_k: int):
@@ -73,7 +80,8 @@ def serve_search(n_queries: int, ranked: bool = False, top_k: int = 10):
     dt = time.perf_counter() - t0
     label = "ranked top-%d" % top_k if ranked else "phrase"
     print(f"[serve/search] {n_queries} {label} queries in {dt*1e3:.1f} ms "
-          f"({dt/n_queries*1e6:.0f} us/query, CPU, {serve.n_dp} doc shard(s)); "
+          f"({dt/n_queries*1e6:.0f} us/query, {_device_label()}, "
+          f"{serve.n_dp} doc shard(s)); "
           f"hit counts: {[len(r.doc) for r in results[:8]]}...")
     if ranked:
         r = next((r for r in results if r.doc_ids is not None
@@ -149,8 +157,8 @@ def serve_lm(arch: str, n_tokens: int):
         out.append(int(tok[0]))
     dt = time.perf_counter() - t0
     print(f"[serve/lm] {arch} decoded {n_tokens} tokens x batch {B} in "
-          f"{dt*1e3:.0f} ms ({dt/n_tokens*1e3:.1f} ms/token, CPU smoke); "
-          f"first 10: {out[:10]}")
+          f"{dt*1e3:.0f} ms ({dt/n_tokens*1e3:.1f} ms/token, "
+          f"{_device_label()} smoke); first 10: {out[:10]}")
 
 
 def main():
@@ -170,6 +178,7 @@ def main():
     ap.add_argument("--deadline-ms", type=float, default=500.0,
                     help="open-loop per-request deadline")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.mode == "search":
         if args.qps > 0:
             serve_search_open_loop(args.qps, args.duration, args.deadline_ms,

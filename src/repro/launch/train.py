@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import OptimizerConfig
 from repro.train.train_loop import fit
 
@@ -96,6 +97,7 @@ def main():
                     help="full-scale config (dry-run scale; not for CPU)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = get_arch(args.arch)
     cfg = spec.make_config() if args.full else spec.make_smoke_config()

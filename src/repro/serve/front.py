@@ -199,7 +199,8 @@ class ShardBackend:
     exists — see the module docstring's bit-identity contract."""
 
     def __init__(self, index: IndexSet, doc_base: int = 0, occ_counts=None,
-                 batch_impl: str = "ref", interpret: bool = True):
+                 batch_impl: str | None = None,
+                 interpret: bool | None = None):
         self.doc_base = int(doc_base)
         self.n_docs = index.n_docs
         # doc_base reaches the engine too: its batched rows then sit on the
@@ -223,7 +224,8 @@ class ShardBackend:
 
 def build_doc_shards(corpus: Corpus, index: IndexSet, n_shards: int,
                      replicate: bool = False,
-                     batch_impl: str = "ref", interpret: bool = True):
+                     batch_impl: str | None = None,
+                     interpret: bool | None = None):
     """Split `corpus` into `n_shards` contiguous doc ranges, build a full
     IndexSet per range, and wrap each in a ShardBackend planning with the
     GLOBAL index's occurrence counts.  Returns (backends, replicas) —
@@ -355,7 +357,8 @@ class FrontDoor:
                  replicas: Optional[Sequence[ShardBackend]] = None,
                  cfg: FrontDoorConfig = FrontDoorConfig(),
                  clock: Callable[[], float] = time.monotonic,
-                 batch_impl: str = "ref", interpret: bool = True,
+                 batch_impl: str | None = None,
+                 interpret: bool | None = None,
                  segments=None):
         self.cfg = cfg
         self.clock = clock

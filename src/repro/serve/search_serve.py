@@ -18,9 +18,10 @@ tensorization is shard-segmented (batch_executor._build_rows): each
 execution row targets exactly one doc shard, so a row's fetches live wholly
 inside one dp shard's arena and carry an `owner` column.  Inside shard_map every device executes only
 its own rows (others are masked inactive), and the per-row results — each
-produced on exactly one device — are combined with a single `pmin` over the
-dp axes.  The `model` axis replicates the index and serves to scale query
-throughput (the launcher round-robins query batches over it).
+produced on exactly one device — are combined with a single `psum` over the
+dp axes (the owner's key + 1, zero elsewhere).  The `model` axis replicates
+the index and serves to scale query throughput (the launcher round-robins
+query batches over it).
 
 Per-row work is O(the row's own postings): no device ever re-sorts another
 shard's slab, so adding doc shards adds rows (capacity) without inflating
@@ -45,6 +46,7 @@ from repro.core.executor import SENTINEL, _next_pow2
 from repro.core.fetch_tables import batch_table_specs
 from repro.core.kword import MODE_KWORD
 from repro.core.planner import MODE_PHRASE, Planner
+from repro.kernels.ops import resolve_kernels
 
 __all__ = ["SearchServeConfig", "SearchServe", "arena_specs",
            "query_table_specs", "make_search_serve_step"]
@@ -82,8 +84,9 @@ class SearchServeConfig:
                                    # shard; 0 = n_arena (a ~32-bit/posting
                                    # budget — generous: doc/pos/dist widths
                                    # at bench scale average well under that)
-    impl: str = "ref"              # intersect implementation (ref | pallas)
-    interpret: bool = True         # pallas interpreter (True on CPU hosts)
+    impl: str | None = None        # kernels: ref | pallas; None = platform's
+                                   # choice (ops.resolve_kernels)
+    interpret: bool | None = None  # pallas interpreter; None = off on a TPU
     ranked: bool = False           # dry-run cells: lower the proximity-scored
                                    # step variant (serving always compiles
                                    # both lazily as ranked requests arrive)
@@ -142,7 +145,7 @@ def query_table_specs(cfg: SearchServeConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# the serve step: shard_map'd bucket math + one pmin merge
+# the serve step: shard_map'd bucket math + one psum merge
 # ---------------------------------------------------------------------------
 
 
@@ -154,7 +157,7 @@ def make_search_serve_step(cfg: SearchServeConfig, mesh,
     """Returns step(arenas, tables) -> (keys [T, F*P0] int64, found bool)
     — plus proximity scores [T, F*P0] float32 when `ranked` (default:
     cfg.ranked), computed by the SAME bucket math the engine jit's and
-    merged across shards right after the int64 pmin (scores ride a pmax:
+    merged across shards right after the int64 psum (scores ride a pmax:
     every row is owned by exactly one dp shard, so both collectives are
     pure "take the owner's result").
 
@@ -166,6 +169,7 @@ def make_search_serve_step(cfg: SearchServeConfig, mesh,
     """
     if ranked is None:
         ranked = cfg.ranked
+    impl, interpret = resolve_kernels(cfg.impl, cfg.interpret)
     dp = _dp_axes(mesh)
     # cfg gives the CAP pads (the dry-run cell shapes); the serve executor's
     # tier ladder lowers tighter variants for the live plan population
@@ -185,19 +189,22 @@ def make_search_serve_step(cfg: SearchServeConfig, mesh,
         arena["near_stop"] = arenas["basic_ns"][0]
         out = bucket_step_math(
             arena, tt,
-            P0=P0, P=Pc, impl=cfg.impl, interpret=cfg.interpret,
+            P0=P0, P=Pc, impl=impl, interpret=interpret,
             ranked=ranked, kword=kword)
         if ranked:
             a64, found, scores = out
         else:
             a64, found = out
-        a64 = jnp.where(found & own[:, None], a64, SENTINEL)
-        a64 = jax.lax.pmin(a64, dp)
+        # every row is owned by exactly one dp shard, so the merge takes
+        # the owner's result: an int64 sum of key + 1 on the owner and 0
+        # elsewhere (a TPU all-reduce lowers 64-bit integers only as a sum)
+        own_hit = found & own[:, None]
+        s = jax.lax.psum(jnp.where(own_hit, a64 + 1, 0), dp)
+        hit = s > 0
+        a64 = jnp.where(hit, s - 1, SENTINEL)
         if not ranked:
-            return a64, a64 < SENTINEL
-        scores = jnp.where(found & own[:, None], scores, -1.0)
-        scores = jax.lax.pmax(scores, dp)
-        hit = a64 < SENTINEL
+            return a64, hit
+        scores = jax.lax.pmax(jnp.where(own_hit, scores, -1.0), dp)
         return a64, hit, jnp.where(hit, scores, 0.0)
 
     spec_shard = P(dp)
@@ -479,7 +486,7 @@ class SearchServe:
     """End-to-end distributed serving facade: SearchRequests → plan → serve
     tables → shard_map step → merged SearchResponses, bit-identical to
     `engine.search_batch` — ranked top-k included (the scoring pass is the
-    same bucket math, merged right after the cross-shard pmin).
+    same bucket math, merged right after the cross-shard psum).
 
     Plans that exceed the fixed table shapes run through the flexible
     executor host-side (the same escape hatch the engine uses)."""

@@ -134,6 +134,31 @@ def test_search_batch_pallas_matches_ref(small_world):
         assert np.array_equal(a.doc, b.doc) and np.array_equal(a.pos, b.pos)
 
 
+@pytest.mark.parametrize("kind", ["ranked", "kword", "ranked_kword"])
+def test_search_batch_pallas_matches_ref_scored(small_world, kword_queries,
+                                                kind):
+    """The Pallas kernels (interpret mode here; compiled on a TPU, where
+    they are the default) give the ref path's bits on the scoring and
+    K-word bucket steps too: min-delta scores, the delta-mask span join,
+    postings accounting."""
+    from repro.core.kword import MODE_KWORD
+    eng_p = AdditionalIndexEngine(small_world["index"], batch_impl="pallas")
+    eng_r = small_world["engine"]
+    if kind == "ranked":
+        queries, modes = _mixed_batch(small_world, n=8, seed=29)
+        reqs = [SearchRequest(q, mode=m, rank=True, top_k=5)
+                for q, m in zip(queries, modes)]
+    else:
+        reqs = [SearchRequest(q, mode=MODE_KWORD, window=w,
+                              rank=kind == "ranked_kword")
+                for q, w, _ in kword_queries[:8]]
+    for a, b in zip(eng_p.search_batch(reqs), eng_r.search_batch(reqs)):
+        assert _same_result(a, b)
+        assert np.array_equal(a.doc_ids, b.doc_ids)
+        assert np.array_equal(a.doc_scores, b.doc_scores)
+        assert np.array_equal(a.anchor_scores, b.anchor_scores)
+
+
 def test_search_batch_max_results(small_world):
     eng = small_world["engine"]
     queries, modes = _mixed_batch(small_world, n=6, seed=13)
