@@ -1,0 +1,5 @@
+"""Seconds from process start to the first due request."""
+
+
+def read(ctx):
+    return ctx.setup_s
