@@ -192,10 +192,12 @@ def run_front(w, queries, batch_size: int = 64,
                     and np.array_equal(r1.pos, r2.pos)
                     and r1.postings_read == r2.postings_read):
                 mismatched += 1
+    p50, p95, p99 = np.percentile([r.latency_ms for r in results],
+                                  [50, 95, 99])
     return {"qps": len(reqs) / elapsed,
-            "p50_ms": stats.percentile(50),
-            "p95_ms": stats.percentile(95),
-            "p99_ms": stats.percentile(99),
+            "p50_ms": float(p50),
+            "p95_ms": float(p95),
+            "p99_ms": float(p99),
             "shed": stats.shed,
             "non_exact": sum(r.status != "SERVED_EXACT" for r in results),
             "result_mismatches": mismatched}

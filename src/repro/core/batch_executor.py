@@ -53,13 +53,16 @@ just not batched.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import threading
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.api import SearchRequest
 from repro.core.builder import IndexSet
 from repro.core.executor import (SENTINEL, Executor, SearchResult,
@@ -308,8 +311,9 @@ def bucket_step_math(arena, t, *,
         iota = jnp.arange(Pw, dtype=jnp.int32)
         idx = jnp.clip(start[..., None] + iota, 0, A - 1)
         valid = iota < length[..., None]
-        doc, pos, dist = unpack_postings(arena, idx, implementation=impl,
-                                         interpret=interpret)
+        with jax.named_scope("unpack"):
+            doc, pos, dist = unpack_postings(arena, idx, implementation=impl,
+                                             interpret=interpret)
         valid &= (req[..., None] == NO_DIST) | (dist == req[..., None])
         valid &= jnp.abs(dist) <= maxab[..., None]
         valid &= t["active"][:, sl, None, None]
@@ -325,26 +329,6 @@ def bucket_step_math(arena, t, *,
         delta = jnp.where(sfd[..., None], jnp.abs(dist), 0)
         return idx, jnp.where(valid, gk, SENTINEL), delta
 
-    idx0, gk0, delta0 = gather(slice(0, 1), P0)
-    gk0 = gk0[:, 0]                                            # [T, F, P0]
-
-    # near-stop verification on the seed group (type-4 pivot checks)
-    C = t["ns_packed"].shape[1]
-    if C > 0:
-        nb = near_stop.shape[0]
-        ns = near_stop[jnp.clip(idx0[:, 0], 0, nb - 1)]        # [T, F, P0, K]
-        ok = jnp.ones((T, F, P0), bool)
-        Mns = t["ns_packed"].shape[2]
-        for c in range(C):
-            hit_c = jnp.zeros((T, F, P0), bool)
-            for m in range(Mns):
-                tgt = t["ns_packed"][:, c, m][:, None, None, None]
-                val = t["ns_valid"][:, c, m][:, None, None]
-                hit_c |= (ns == tgt).any(axis=-1) & val
-            has_check = t["ns_valid"][:, c].any(axis=-1)[:, None, None]
-            ok &= hit_c | ~has_check
-        gk0 = jnp.where(ok, gk0, SENTINEL)
-
     m26 = (1 << POS_BITS) - 1
 
     def rebase(gk, dt_b, b):
@@ -354,9 +338,31 @@ def bucket_step_math(arena, t, *,
         k32 = jnp.where(dt_b, gk, ((dglob - b) << TABLE_POS_BITS) | (gk & m26))
         return jnp.where(gk < SENTINEL, k32, I32_SENTINEL).astype(jnp.int32)
 
-    a64 = gk0.reshape(T, F * P0)
-    a32 = rebase(gk0, dt1[:, None, None], base[:, None, None]).reshape(T, F * P0)
+    with jax.named_scope("gather"):
+        idx0, gk0, delta0 = gather(slice(0, 1), P0)
+        gk0 = gk0[:, 0]                                        # [T, F, P0]
 
+        # near-stop verification on the seed group (type-4 pivot checks)
+        C = t["ns_packed"].shape[1]
+        if C > 0:
+            nb = near_stop.shape[0]
+            ns = near_stop[jnp.clip(idx0[:, 0], 0, nb - 1)]    # [T, F, P0, K]
+            ok = jnp.ones((T, F, P0), bool)
+            Mns = t["ns_packed"].shape[2]
+            for c in range(C):
+                hit_c = jnp.zeros((T, F, P0), bool)
+                for m in range(Mns):
+                    tgt = t["ns_packed"][:, c, m][:, None, None, None]
+                    val = t["ns_valid"][:, c, m][:, None, None]
+                    hit_c |= (ns == tgt).any(axis=-1) & val
+                has_check = t["ns_valid"][:, c].any(axis=-1)[:, None, None]
+                ok &= hit_c | ~has_check
+            gk0 = jnp.where(ok, gk0, SENTINEL)
+        a64 = gk0.reshape(T, F * P0)
+        a32 = rebase(gk0, dt1[:, None, None],
+                     base[:, None, None]).reshape(T, F * P0)
+
+    @jax.named_scope("intersect")
     def kword_found(b32_sorted):
         """K-way windowed span join (kword buckets): per-group signed delta
         masks, window-start scans ANDed across groups (core/kword.py;
@@ -375,6 +381,15 @@ def bucket_step_math(arena, t, *,
         active = t["active"][:, 1:].transpose(1, 0)
         return kword_window_hits(masks, active, kw_bands)
 
+    @jax.named_scope("gather")
+    def gather_constraints():
+        """Constraint groups' int32 row keys [T, G-1, F*P] (+ their score
+        deltas when ranked)."""
+        _, gkc, deltac = gather(slice(1, None), P)             # [T, G-1, F, P]
+        b32 = rebase(gkc, dt1[:, None, None, None],
+                     base[:, None, None, None]).reshape(T, G - 1, F * P)
+        return b32, deltac
+
     if ranked:
         # proximity scores, canonical accumulation order (mirrored exactly by
         # Executor._run_groups_ranked): per-task bias, the seed's own delta,
@@ -384,42 +399,49 @@ def bucket_step_math(arena, t, *,
         score = t["score_bias"][:, None] + proximity_w(delta0[:, 0].reshape(T, F * P0))
         found = jnp.ones((T, F * P0), bool)
         if G > 1:
-            _, gkc, deltac = gather(slice(1, None), P)         # [T, G-1, F, P]
-            b32 = rebase(gkc, dt1[:, None, None, None],
-                         base[:, None, None, None]).reshape(T, G - 1, F * P)
+            b32, deltac = gather_constraints()
             dl = deltac.reshape(T, G - 1, F * P)
             bands = t["band"][:, 1:]                           # [T, G-1]
             if impl == "pallas":
-                b_sorted = jnp.sort(
-                    jnp.where(b32 == I32_SENTINEL, jnp.int64(1) << 40,
-                              (b32.astype(jnp.int64) << SCORE_DELTA_BITS)
-                              | dl.astype(jnp.int64)), axis=-1)
-                bk = (b_sorted >> SCORE_DELTA_BITS).astype(jnp.int32)
-                bk = jnp.where(b_sorted >= jnp.int64(1) << 40, I32_SENTINEL, bk)
-                bd = (b_sorted & ((1 << SCORE_DELTA_BITS) - 1)).astype(jnp.int32)
-                a_rows = jnp.broadcast_to(a32[:, None], (T, G - 1, F * P0))
-                delta_g = banded_min_delta_rows(
-                    a_rows.reshape(T * (G - 1), F * P0),
-                    bk.reshape(T * (G - 1), F * P),
-                    bd.reshape(T * (G - 1), F * P),
-                    jnp.broadcast_to(bands, (T, G - 1)).reshape(-1),
-                    implementation=impl, interpret=interpret)
-                delta_g = delta_g.reshape(T, G - 1, F * P0)
+                with jax.named_scope("sort"):
+                    b_sorted = jnp.sort(
+                        jnp.where(b32 == I32_SENTINEL, jnp.int64(1) << 40,
+                                  (b32.astype(jnp.int64) << SCORE_DELTA_BITS)
+                                  | dl.astype(jnp.int64)), axis=-1)
+                    bk = (b_sorted >> SCORE_DELTA_BITS).astype(jnp.int32)
+                    bk = jnp.where(b_sorted >= jnp.int64(1) << 40,
+                                   I32_SENTINEL, bk)
+                    bd = (b_sorted
+                          & ((1 << SCORE_DELTA_BITS) - 1)).astype(jnp.int32)
+                with jax.named_scope("intersect"):
+                    a_rows = jnp.broadcast_to(a32[:, None],
+                                              (T, G - 1, F * P0))
+                    delta_g = banded_min_delta_rows(
+                        a_rows.reshape(T * (G - 1), F * P0),
+                        bk.reshape(T * (G - 1), F * P),
+                        bd.reshape(T * (G - 1), F * P),
+                        jnp.broadcast_to(bands, (T, G - 1)).reshape(-1),
+                        implementation=impl, interpret=interpret)
+                    delta_g = delta_g.reshape(T, G - 1, F * P0)
             else:
                 pad = jnp.int64(1) << 40
-                comp = jnp.where(
-                    b32 == I32_SENTINEL, pad,
-                    (b32.astype(jnp.int64) << SCORE_DELTA_BITS)
-                    | dl.astype(jnp.int64))
-                comp = jnp.sort(comp, axis=-1)
-                probe = jnp.where(a32 == I32_SENTINEL, pad,
-                                  a32.astype(jnp.int64) << SCORE_DELTA_BITS)
-                probe = jnp.broadcast_to(probe[:, None], (T, G - 1, F * P0))
-                delta_g = scored_probe(
-                    comp.reshape(T * (G - 1), F * P),
-                    probe.reshape(T * (G - 1), F * P0),
-                    jnp.broadcast_to(bands, (T, G - 1)).reshape(-1, 1))
-                delta_g = delta_g.reshape(T, G - 1, F * P0)
+                with jax.named_scope("sort"):
+                    comp = jnp.where(
+                        b32 == I32_SENTINEL, pad,
+                        (b32.astype(jnp.int64) << SCORE_DELTA_BITS)
+                        | dl.astype(jnp.int64))
+                    comp = jnp.sort(comp, axis=-1)
+                with jax.named_scope("intersect"):
+                    probe = jnp.where(
+                        a32 == I32_SENTINEL, pad,
+                        a32.astype(jnp.int64) << SCORE_DELTA_BITS)
+                    probe = jnp.broadcast_to(probe[:, None],
+                                             (T, G - 1, F * P0))
+                    delta_g = scored_probe(
+                        comp.reshape(T * (G - 1), F * P),
+                        probe.reshape(T * (G - 1), F * P0),
+                        jnp.broadcast_to(bands, (T, G - 1)).reshape(-1, 1))
+                    delta_g = delta_g.reshape(T, G - 1, F * P0)
             active_c = t["active"][:, 1:, None]
             for gi in range(G - 1):
                 hit_g = delta_g[:, gi] < I32_SENTINEL
@@ -431,26 +453,28 @@ def bucket_step_math(arena, t, *,
                 # span match implies an in-band hit for every group, so the
                 # score accumulated above is exact for every survivor (and
                 # zeroed below for the rest)
-                found = kword_found(jnp.sort(b32, axis=-1))
+                with jax.named_scope("sort"):
+                    b32 = jnp.sort(b32, axis=-1)
+                found = kword_found(b32)
         found &= a32 != I32_SENTINEL
         return a64, found, jnp.where(found, score, 0.0)
     if G > 1:
-        _, gkc, _ = gather(slice(1, None), P)                  # [T, G-1, F, P]
-        b32 = rebase(gkc, dt1[:, None, None, None],
-                     base[:, None, None, None]).reshape(T, G - 1, F * P)
+        b32, _ = gather_constraints()
         if not presorted:
-            b32 = jnp.sort(b32, axis=-1)
+            with jax.named_scope("sort"):
+                b32 = jnp.sort(b32, axis=-1)
         if kword:
             found = kword_found(b32)
             return a64, found & (a32 != I32_SENTINEL)
-        a_rows = jnp.broadcast_to(a32[:, None], (T, G - 1, F * P0))
-        hit = banded_intersect_rows(
-            a_rows.reshape(T * (G - 1), F * P0),
-            b32.reshape(T * (G - 1), F * P),
-            jnp.broadcast_to(t["band"][:, 1:], (T, G - 1)).reshape(-1),
-            implementation=impl, interpret=interpret)
-        hit = hit.reshape(T, G - 1, F * P0) | ~t["active"][:, 1:, None]
-        found = hit.all(axis=1)
+        with jax.named_scope("intersect"):
+            a_rows = jnp.broadcast_to(a32[:, None], (T, G - 1, F * P0))
+            hit = banded_intersect_rows(
+                a_rows.reshape(T * (G - 1), F * P0),
+                b32.reshape(T * (G - 1), F * P),
+                jnp.broadcast_to(t["band"][:, 1:], (T, G - 1)).reshape(-1),
+                implementation=impl, interpret=interpret)
+            hit = hit.reshape(T, G - 1, F * P0) | ~t["active"][:, 1:, None]
+            found = hit.all(axis=1)
     else:
         found = jnp.ones((T, F * P0), bool)
     return a64, found & (a32 != I32_SENTINEL)
@@ -484,6 +508,13 @@ class BatchExecutor:
         self._pos_budget = (1 << TABLE_POS_BITS) - PHRASE_BIAS \
             - self.dev.max_pos - max(self.dev.max_distance,
                                      self.dev.max_shift)
+        # bucket-step calls: padded slab against live rows and gathered
+        # postings (elements), and calls of a step key not run before;
+        # shard dispatchers may call one executor from several threads
+        self.slab_stats = {"steps": 0, "slab_rows": 0, "live_rows": 0,
+                           "slab_elems": 0, "live_elems": 0, "first_runs": 0}
+        self._ran: set = set()
+        self._stats_lock = threading.Lock()
 
     # -- tensorization ------------------------------------------------------
 
@@ -717,8 +748,9 @@ class BatchExecutor:
         """Yield (rows, device tables, static step kwargs), one per jit'd
         bucket-step call over `rows`."""
         buckets: dict = {}
-        for row in rows:
-            buckets.setdefault(self._bucket_key(row), []).append(row)
+        with obs.span("batch.rows"):
+            for row in rows:
+                buckets.setdefault(self._bucket_key(row), []).append(row)
         d = self.dev
         for (G, F, P0, P, C, M, sortfree, ranked, kword), rs in buckets.items():
             per_task = F * P0 + (G - 1) * F * P
@@ -731,29 +763,63 @@ class BatchExecutor:
                 # padding them to a large T multiplies the gather/sort slab;
                 # the extra pow2 compile variants are absorbed by warm-up
                 T_pad = _next_pow2(len(part), floor=4)
-                t = self._tensorize_bucket(part, G, F, C, M, T_pad)
+                with obs.span("batch.tensorize"):
+                    t = self._tensorize_bucket(part, G, F, C, M, T_pad)
                 # the score columns are only read by the ranked program —
                 # keep them off the per-call transfer path for unranked
                 # buckets (device_put per table entry is the step's fixed
                 # cost at smoke scale)
-                tj = {k: jnp.asarray(v) for k, v in t.items()
-                      if ranked or k not in ("score_bias", "score_from_dist")}
+                with obs.span("batch.transfer"):
+                    tj = {k: jnp.asarray(v) for k, v in t.items()
+                          if ranked or k not in ("score_bias",
+                                                 "score_from_dist")}
                 yield part, tj, dict(P0=P0, P=P, impl=self.impl,
                                      interpret=self.interpret,
                                      presorted=sortfree, ranked=ranked,
                                      kword=kword)
 
+    def _count_slab(self, part: list, T: int, volume: int):
+        """Count one bucket-step call of `part` padded to T rows, each of
+        `volume` gathered postings (F*P0 + (G-1)*F*P)."""
+        live = sum(ln for row in part for g in row.groups
+                   for _, _, ln in g.slots)
+        with self._stats_lock:
+            st = self.slab_stats
+            st["steps"] += 1
+            st["slab_rows"] += T
+            st["live_rows"] += len(part)
+            st["slab_elems"] += T * volume
+            st["live_elems"] += live
+
+    @staticmethod
+    def _step_key(tj: dict, static: dict) -> tuple:
+        """A bucket-step call's identity: its static arguments and table
+        shapes (what selects a compiled program)."""
+        return (tuple(sorted(static.items())),
+                tuple(sorted((k, v.shape) for k, v in tj.items())))
+
+    def _first_run(self, key: tuple):
+        """A `batch.first_run` span (and a count) around a step call of a
+        key this executor has not run before; nothing otherwise."""
+        with self._stats_lock:
+            if key in self._ran:
+                return contextlib.nullcontext()
+            self._ran.add(key)
+            self.slab_stats["first_runs"] += 1
+        return obs.span("batch.first_run")
+
     def _run_rows(self, rows: list):
         for part, tj, static in self._bucket_chunks(rows):
-            out = _batch_step(self.dev.device_arena, tj, **static)
-            if static["ranked"]:
-                a64, found, scores = out
-                self._scatter_row_keys(part, np.asarray(a64),
-                                       np.asarray(found), np.asarray(scores))
-            else:
-                a64, found = out
-                self._scatter_row_keys(part, np.asarray(a64),
-                                       np.asarray(found))
+            T, G, F = tj["start"].shape
+            self._count_slab(part, T, F * static["P0"]
+                             + (G - 1) * F * static["P"])
+            with self._first_run(self._step_key(tj, static)):
+                with obs.span("batch.step"):
+                    out = _batch_step(self.dev.device_arena, tj, **static)
+                with obs.span("batch.fetch"):
+                    out = [np.asarray(x) for x in out]
+            with obs.span("batch.scatter"):
+                self._scatter_row_keys(part, *out)
 
     def lower_steps(self, plans: list[QueryPlan],
                     requests: list[SearchRequest]) -> dict:
@@ -769,8 +835,7 @@ class BatchExecutor:
         rows = [r for t in tasks if not t.fallback for r in t.rows]
         out = {}
         for _, tj, static in self._bucket_chunks(rows):
-            key = (tuple(sorted(static.items())),
-                   tuple(sorted((k, v.shape) for k, v in tj.items())))
+            key = self._step_key(tj, static)
             if key not in out:
                 out[key] = _batch_step.lower(self.dev.device_arena, tj,
                                              **static)
@@ -821,29 +886,35 @@ class BatchExecutor:
         tasks: list[_Task] = []
         flex_plans: dict[int, QueryPlan] = {}
         plan_tasks: dict[int, list] = {}
-        for i, plan in enumerate(plans):
-            start = len(tasks)
-            if self._build_tasks(i, plan, tasks, ranked=requests[i].rank):
-                plan_tasks[i] = tasks[start:]
-            else:
-                flex_plans[i] = plan
+        with obs.span("batch.rows"):
+            for i, plan in enumerate(plans):
+                start = len(tasks)
+                if self._build_tasks(i, plan, tasks, ranked=requests[i].rank):
+                    plan_tasks[i] = tasks[start:]
+                else:
+                    flex_plans[i] = plan
+            main_rows = [r for t in tasks if not t.fallback for r in t.rows]
         # round 1: main rows; round 2: only the fallback rows whose main
         # result came back empty (mirrors the flexible executor, which never
         # touches stream 1 when the positional search hits)
-        self._run_rows([r for t in tasks if not t.fallback for r in t.rows])
-        main_keys = {(t.plan_i, t.subplan_i): t.collect_keys()
-                     for t in tasks if not t.fallback}
-        self._run_rows([r for t in tasks if t.fallback
-                        and len(main_keys.get((t.plan_i, t.subplan_i),
-                                              np.empty(0))) == 0
-                        for r in t.rows])
+        self._run_rows(main_rows)
+        with obs.span("batch.rows"):
+            main_keys = {(t.plan_i, t.subplan_i): t.collect_keys()
+                         for t in tasks if not t.fallback}
+            fallback_rows = [r for t in tasks if t.fallback
+                             and len(main_keys.get((t.plan_i, t.subplan_i),
+                                                   np.empty(0))) == 0
+                             for r in t.rows]
+        self._run_rows(fallback_rows)
         out: list[SearchResult | None] = [None] * len(plans)
-        for i, plan in enumerate(plans):
-            if i in flex_plans:
-                out[i] = self.flex.execute(plan, request=requests[i])
-            else:
-                task_map = {(t.subplan_i, t.fallback): t for t in plan_tasks[i]}
-                out[i] = self._merge_plan(plan, task_map, requests[i])
+        with obs.span("batch.merge"):
+            for i, plan in enumerate(plans):
+                if i in flex_plans:
+                    out[i] = self.flex.execute(plan, request=requests[i])
+                else:
+                    task_map = {(t.subplan_i, t.fallback): t
+                                for t in plan_tasks[i]}
+                    out[i] = self._merge_plan(plan, task_map, requests[i])
         return out
 
 

@@ -23,6 +23,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.core.analyzer import Analyzer
 from repro.core.api import SearchRequest, SearchResponse, as_request
 from repro.core.batch_executor import BatchExecutor
@@ -97,7 +98,8 @@ class _BatchSearchMixin:
             requests = _coerce_requests(
                 requests, modes, window, max_results,
                 what=f"{type(self).__name__}.search_batch")
-        plans = [self.plan_request(r) for r in requests]
+        with obs.span("engine.plan"):
+            plans = [self.plan_request(r) for r in requests]
         return self.batch_executor.execute_batch(plans, requests=requests)
 
 

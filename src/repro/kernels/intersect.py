@@ -118,9 +118,9 @@ def _rows_kernel(step_for, init):
     return kernel
 
 
-def _rows_call(kernel, a2d, b_planes, lo_tiles, n_tiles, bands, *, block_a,
-               block_b, max_tiles, interpret):
-    """pallas_call over (a-block, visited b tile).  Blocks are whole
+def _rows_call(kernel, a2d, b_planes, lo_tiles, n_tiles, bands, *, name,
+               block_a, block_b, max_tiles, interpret):
+    """pallas_call `name` over (a-block, visited b tile).  Blocks are whole
     (8k, 128) int32 tiles.  The b index map walks lo .. lo + n - 1 and then
     holds the last visited block, so skipped steps issue no new DMA."""
     assert block_a % TILE == 0 and block_b % TILE == 0, (block_a, block_b)
@@ -144,6 +144,7 @@ def _rows_call(kernel, a2d, b_planes, lo_tiles, n_tiles, bands, *, block_a,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(a2d.shape, jnp.int32),
         interpret=interpret,
+        name=name,
     )
     return fn(lo_tiles, n_tiles, bands, a2d, *b_planes)
 
@@ -161,8 +162,9 @@ def banded_intersect_rows_pallas(a2d: jax.Array, b2d: jax.Array,
     i.e. already offset to the owning row's b segment), number of b blocks to
     visit, and the row's band width (see ops.banded_intersect_rows)."""
     return _rows_call(_rows_kernel(_hit_step, 0), a2d, (b2d,), lo_tiles,
-                      n_tiles, bands, block_a=block_a, block_b=block_b,
-                      max_tiles=max_tiles, interpret=interpret)
+                      n_tiles, bands, name="intersect", block_a=block_a,
+                      block_b=block_b, max_tiles=max_tiles,
+                      interpret=interpret)
 
 
 def banded_min_delta_rows_pallas(a2d: jax.Array, bk2d: jax.Array,
@@ -178,8 +180,8 @@ def banded_min_delta_rows_pallas(a2d: jax.Array, bk2d: jax.Array,
     banded_intersect_rows_pallas, plus the aligned b_delta planes."""
     return _rows_call(_rows_kernel(_min_delta_step, I32_SENTINEL), a2d,
                       (bk2d, bd2d), lo_tiles, n_tiles, bands,
-                      block_a=block_a, block_b=block_b, max_tiles=max_tiles,
-                      interpret=interpret)
+                      name="min_delta", block_a=block_a, block_b=block_b,
+                      max_tiles=max_tiles, interpret=interpret)
 
 
 def banded_delta_mask_rows_pallas(a2d: jax.Array, b2d: jax.Array,
@@ -195,6 +197,6 @@ def banded_delta_mask_rows_pallas(a2d: jax.Array, b2d: jax.Array,
     bit index (d + band) <= 30 fits an int32 lane.  Layout as
     banded_intersect_rows_pallas."""
     return _rows_call(_rows_kernel(_delta_mask_step, 0), a2d, (b2d,),
-                      lo_tiles, n_tiles, bands, block_a=block_a,
-                      block_b=block_b, max_tiles=max_tiles,
+                      lo_tiles, n_tiles, bands, name="delta_mask",
+                      block_a=block_a, block_b=block_b, max_tiles=max_tiles,
                       interpret=interpret)

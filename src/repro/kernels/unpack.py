@@ -61,6 +61,7 @@ def unpack_fields_pallas(words: jax.Array, shifts: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret,
+        name="unpack",
     )
     out = fn(prep(words), prep(shifts), prep(widths), prep(anchors))
     return out.reshape(-1)[:n]

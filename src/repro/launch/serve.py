@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.registry import get_arch
 from repro.launch.compile_cache import enable_compile_cache
 
@@ -116,6 +117,7 @@ def serve_search_open_loop(qps: float, duration: float, deadline_ms: float,
         n *= 2
     front.search_batch(warm)
     front.stats = type(front.stats)()
+    gcs0, gc_s0 = obs.GC.collections, obs.GC.pause_s
 
     rng = np.random.default_rng(1)
     tickets = []
@@ -138,7 +140,10 @@ def serve_search_open_loop(qps: float, duration: float, deadline_ms: float,
           f"({len(resps)} requests, deadline {deadline_ms:.0f} ms): "
           f"p50 {p50:.1f} ms, p95 {p95:.1f} ms, p99 {p99:.1f} ms; "
           f"exact {st.served_exact}, degraded {st.served_degraded}, "
-          f"shed {st.shed} (shed_rate {st.shed_rate:.3f})")
+          f"shed {st.shed} (shed_rate {st.shed_rate:.3f}); queue wait "
+          f"{1e3 * st.queue_wait_s / max(st.dequeued, 1):.1f} ms mean; "
+          f"{obs.GC.collections - gcs0} gc pauses, "
+          f"{1e3 * (obs.GC.pause_s - gc_s0):.1f} ms")
 
 
 def serve_lm(arch: str, n_tokens: int):
@@ -179,6 +184,7 @@ def main():
                     help="open-loop per-request deadline")
     args = ap.parse_args()
     enable_compile_cache()
+    obs.trace_gc()
     if args.mode == "search":
         if args.qps > 0:
             serve_search_open_loop(args.qps, args.duration, args.deadline_ms,

@@ -77,6 +77,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.api import (STATUS_SERVED_DEGRADED, STATUS_SERVED_EXACT,
                             STATUS_SHED, SearchRequest, SearchResponse)
 from repro.core.builder import IndexSet, build_all
@@ -128,8 +129,9 @@ class TokenBucket:
 
 @dataclasses.dataclass
 class FrontStats:
-    """Counters + latency reservoir; the no-silent-drop ledger
-    (submitted == served_exact + served_degraded + shed, always)."""
+    """Counters; the no-silent-drop ledger
+    (submitted == served_exact + served_degraded + shed, always).  Each
+    response carries its own `latency_ms`."""
     submitted: int = 0
     served_exact: int = 0
     served_degraded: int = 0
@@ -142,8 +144,9 @@ class FrontStats:
     flex_routed: int = 0
     batches: int = 0
     retries: int = 0
+    dequeued: int = 0           # requests the dispatcher took off the queue
+    queue_wait_s: float = 0.0   # their summed (taken - arrival)
     shed_reasons: dict = dataclasses.field(default_factory=dict)
-    latencies_ms: list = dataclasses.field(default_factory=list)
 
     @property
     def responded(self) -> int:
@@ -152,11 +155,6 @@ class FrontStats:
     @property
     def shed_rate(self) -> float:
         return self.shed / max(self.submitted, 1)
-
-    def percentile(self, p: float) -> float:
-        if not self.latencies_ms:
-            return 0.0
-        return float(np.percentile(np.asarray(self.latencies_ms), p))
 
 
 class _Ticket:
@@ -212,13 +210,14 @@ class ShardBackend:
                                             doc_base=doc_base)
 
     def __call__(self, requests: Sequence[SearchRequest]) -> list[SearchResponse]:
-        resps = self.engine.search_batch(list(requests))
-        if self.doc_base:
-            base = np.int32(self.doc_base)
-            for r in resps:
-                r.doc = r.doc + base
-                if r.doc_ids is not None:
-                    r.doc_ids = r.doc_ids + base
+        with obs.span("engine.search_batch"):
+            resps = self.engine.search_batch(list(requests))
+            if self.doc_base:
+                base = np.int32(self.doc_base)
+                for r in resps:
+                    r.doc = r.doc + base
+                    if r.doc_ids is not None:
+                        r.doc_ids = r.doc_ids + base
         return resps
 
 
@@ -550,20 +549,41 @@ class FrontDoor:
                 self.stats.served_degraded += 1
             if cache_hit:
                 self.stats.cache_hits += 1
-            self.stats.latencies_ms.append(resp.latency_ms)
         t.response = resp
         t._event.set()
 
     # -- dispatcher thread --------------------------------------------------
 
     def _loop(self):
+        seq = 0
         while not self._closed:
             try:
                 first = self._queue.get(timeout=0.05)
             except queue.Empty:
                 continue
-            batch = [first]
-            window_end = min(self.clock() + self.cfg.batch_window_ms / 1000.0,
+            seq += 1
+            with obs.span("front.batch", seq=seq) as sp:
+                batch = self._coalesce(first)
+                sp.set_metadata(size=len(batch))
+                try:
+                    if self._resync:
+                        self._sync_segments()
+                    self._dispatch_batch(batch)
+                except Exception:                    # pragma: no cover
+                    # a dispatcher bug must not silently strand tickets
+                    for t in batch:
+                        if not t.done():
+                            self._shed(t, "internal_error")
+
+    def _coalesce(self, first: _Ticket) -> list:
+        """The micro-batch behind `first` (just taken off the queue): more
+        tickets within the batch window, clipped to the earliest deadline.
+        Counts the tickets taken and their time in the queue."""
+        now = self.clock()
+        wait = now - first.arrival
+        batch = [first]
+        with obs.span("front.coalesce"):
+            window_end = min(now + self.cfg.batch_window_ms / 1000.0,
                              first.deadline)
             while len(batch) < self.cfg.max_batch:
                 rem = window_end - self.clock()
@@ -573,17 +593,13 @@ class FrontDoor:
                     t = self._queue.get(timeout=rem)
                 except queue.Empty:
                     break
+                wait += self.clock() - t.arrival
                 batch.append(t)
                 window_end = min(window_end, t.deadline)
-            try:
-                if self._resync:
-                    self._sync_segments()
-                self._dispatch_batch(batch)
-            except Exception:                        # pragma: no cover
-                # a dispatcher bug must not silently strand tickets
-                for t in batch:
-                    if not t.done():
-                        self._shed(t, "internal_error")
+        with self._stats_lock:
+            self.stats.dequeued += len(batch)
+            self.stats.queue_wait_s += wait
+        return batch
 
     def _sync_segments(self):
         """Rebuild backends/planner from the segment manager's current
@@ -628,26 +644,27 @@ class FrontDoor:
             self.stats.batches += 1
         now = self.clock()
         buckets: dict[str, list] = {"unranked": [], "ranked": [], "flex": []}
-        for t in batch:
-            if now > t.deadline:
-                self._shed(t, "deadline")
-                continue
-            r = t.request
-            t.plan = self.planner.plan(list(r.surface_ids), mode=r.mode,
-                                       window=r.window, ranked=r.rank)
-            if self._is_overflow(t.plan):
-                # flex escape: the slow path only runs while the deadline
-                # slack still covers its per-request time budget
-                if (t.deadline - now) * 1000.0 < self.cfg.flex_budget_ms:
+        with obs.span("front.plan"):
+            for t in batch:
+                if now > t.deadline:
                     self._shed(t, "deadline")
                     continue
-                with self._stats_lock:
-                    self.stats.flex_routed += 1
-                buckets["flex"].append(t)
-            elif r.rank:
-                buckets["ranked"].append(t)
-            else:
-                buckets["unranked"].append(t)
+                r = t.request
+                t.plan = self.planner.plan(list(r.surface_ids), mode=r.mode,
+                                           window=r.window, ranked=r.rank)
+                if self._is_overflow(t.plan):
+                    # flex escape: the slow path only runs while the deadline
+                    # slack still covers its per-request time budget
+                    if (t.deadline - now) * 1000.0 < self.cfg.flex_budget_ms:
+                        self._shed(t, "deadline")
+                        continue
+                    with self._stats_lock:
+                        self.stats.flex_routed += 1
+                    buckets["flex"].append(t)
+                elif r.rank:
+                    buckets["ranked"].append(t)
+                else:
+                    buckets["unranked"].append(t)
         # jit'd shape buckets first; flex stragglers run after, one by one,
         # so they can never hold a batched bucket's responses hostage
         for key in ("unranked", "ranked"):
@@ -663,20 +680,21 @@ class FrontDoor:
         on_late = None
         if self.cfg.cache_capacity > 0:
             on_late = lambda i, res: self._backfill(slot, i, res)  # noqa: E731
-        results = self.dispatcher.dispatch(reqs, on_late=on_late)
-        missing = [i for i, r in enumerate(results) if r is None]
-        attempt = 0
-        while missing and attempt < self.cfg.max_retries:
-            time.sleep(self.cfg.retry_backoff_ms / 1000.0 * (2 ** attempt))
-            attempt += 1
-            with self._stats_lock:
-                self.stats.retries += 1
-            sub = self.dispatcher.dispatch(reqs, shards=missing,
-                                           on_late=on_late)
-            for i in missing:
-                if sub[i] is not None:
-                    results[i] = sub[i]
+        with obs.span("front.execute"):
+            results = self.dispatcher.dispatch(reqs, on_late=on_late)
             missing = [i for i, r in enumerate(results) if r is None]
+            attempt = 0
+            while missing and attempt < self.cfg.max_retries:
+                time.sleep(self.cfg.retry_backoff_ms / 1000.0 * (2 ** attempt))
+                attempt += 1
+                with self._stats_lock:
+                    self.stats.retries += 1
+                sub = self.dispatcher.dispatch(reqs, shards=missing,
+                                               on_late=on_late)
+                for i in missing:
+                    if sub[i] is not None:
+                        results[i] = sub[i]
+                missing = [i for i, r in enumerate(results) if r is None]
         live = [i for i, r in enumerate(results) if r is not None]
         # arm (or close) the backfill slot: late-shard results re-merge into
         # the cache only while shards are actually missing
@@ -689,31 +707,32 @@ class FrontDoor:
                 slot.done = True
         for i, res in early:        # stragglers that beat the finalize
             self._backfill(slot, i, res)
-        for q_i, t in enumerate(items):
-            if not live:
-                resp = SearchResponse(
-                    doc=np.empty(0, np.int32), pos=np.empty(0, np.int32),
-                    postings_read=0, used_fallback=False, doc_only=False,
-                    ranked=t.request.rank, request=t.request,
-                    status=STATUS_SERVED_DEGRADED, shed_reason="no_shards")
-                if t.request.rank:
-                    resp.anchor_scores = np.empty(0, np.float32)
-                    resp.doc_ids = np.empty(0, np.int32)
-                    resp.doc_scores = np.empty(0, np.float32)
+        with obs.span("front.merge"):
+            for q_i, t in enumerate(items):
+                if not live:
+                    resp = SearchResponse(
+                        doc=np.empty(0, np.int32), pos=np.empty(0, np.int32),
+                        postings_read=0, used_fallback=False, doc_only=False,
+                        ranked=t.request.rank, request=t.request,
+                        status=STATUS_SERVED_DEGRADED, shed_reason="no_shards")
+                    if t.request.rank:
+                        resp.anchor_scores = np.empty(0, np.float32)
+                        resp.doc_ids = np.empty(0, np.int32)
+                        resp.doc_scores = np.empty(0, np.float32)
+                    self._fulfill(t, resp)
+                    continue
+                per_shard = [(s, results[s][q_i]) for s in live]
+                resp = merge_shard_responses(t.request, t.plan, per_shard)
+                resp.shards = tuple(live)
+                late = self.clock() > t.deadline
+                if len(live) == self.n_shards and not late:
+                    resp.status = STATUS_SERVED_EXACT
+                    self._cache_put(t.request, resp, gen=gen0)
+                else:
+                    resp.status = STATUS_SERVED_DEGRADED
+                    resp.shed_reason = "shards" if len(live) < self.n_shards \
+                        else "late"
                 self._fulfill(t, resp)
-                continue
-            per_shard = [(s, results[s][q_i]) for s in live]
-            resp = merge_shard_responses(t.request, t.plan, per_shard)
-            resp.shards = tuple(live)
-            late = self.clock() > t.deadline
-            if len(live) == self.n_shards and not late:
-                resp.status = STATUS_SERVED_EXACT
-                self._cache_put(t.request, resp, gen=gen0)
-            else:
-                resp.status = STATUS_SERVED_DEGRADED
-                resp.shed_reason = "shards" if len(live) < self.n_shards \
-                    else "late"
-            self._fulfill(t, resp)
 
     def _backfill(self, slot: "_BackfillSlot", shard_i: int, res):
         """A shard answered AFTER its dispatch timed out (ShardDispatcher

@@ -252,8 +252,6 @@ class _ServeBatchExecutor(BatchExecutor):
         self.docs_per_dp = dps * self.shards_per_dp
         self._build_dp_arenas(index)
         self._tiers: list | None = None
-        self.slab_stats = {"steps": 0, "slab_rows": 0, "live_rows": 0,
-                           "slab_elems": 0, "live_elems": 0}
         self._steps = {(False, False, cfg.p_seed, cfg.postings_pad):
                        jax.jit(make_search_serve_step(cfg, mesh,
                                                       ranked=False))}
@@ -460,26 +458,15 @@ class _ServeBatchExecutor(BatchExecutor):
                     t["start"][m] = np.searchsorted(self._sel[dd],
                                                     t["start"][m])
                 t["owner"] = owner
-                st = self.slab_stats
-                st["steps"] += 1
-                st["slab_rows"] += T
-                st["live_rows"] += len(part)
-                st["slab_elems"] += T * self._tier_volume((G, F, P0, Pc))
-                st["live_elems"] += sum(
-                    ln for row in part for g in row.groups
-                    for _, _, ln in g.slots)
+                self._count_slab(part, T, self._tier_volume((G, F, P0, Pc)))
                 tj = {k: jnp.asarray(v) for k, v in t.items()}
-                with self.mesh:
-                    out = step(self.arenas, tj)
-                if ranked:
-                    a64, found, scores = out
-                    self._scatter_row_keys(part, np.asarray(a64),
-                                           np.asarray(found),
-                                           np.asarray(scores))
-                else:
-                    a64, found = out
-                    self._scatter_row_keys(part, np.asarray(a64),
-                                           np.asarray(found))
+                key = self._step_key(tj, dict(ranked=ranked, kword=kword,
+                                              P0=P0, P=Pc))
+                with self._first_run(key):
+                    with self.mesh:
+                        out = step(self.arenas, tj)
+                    out = [np.asarray(x) for x in out]
+                self._scatter_row_keys(part, *out)
 
 
 class SearchServe:
