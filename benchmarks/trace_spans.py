@@ -13,7 +13,8 @@ is one JSON object:
 
 * `metrics`: front-door queue wait, front-door host time per micro-batch,
   planning and row-building/tensorizing time per backend call, the bucket
-  steps' live share of their padded slabs, the device's idle share under a
+  steps' live share of their padded slabs, the share of their banded rows
+  run in the kernels' packed layout, the device's idle share under a
   garbage collection, and first runs of a step in the window;
 * `phases_ms`: per backend call (median), the time in each shard-side span;
 * `idle_by_span`: the device's idle seconds, each put down to the
@@ -21,7 +22,9 @@ is one JSON object:
   first, then the shard threads' spans, then the dispatcher's; `none`
   where no span is open);
 * `checks`: the new numbers against the benchmark's own (Little's law for
-  the queue; the shard spans against `engine.host_ms`).
+  the queue; the shard spans against `engine.host_ms`);
+* `device_ops`: the device operations that took most of the window, and
+  `ops_by_kind` the same time summed by kind (all fusions, each kernel).
 
 `--fixture` also records a two-second window and writes its reduced trace
 (for the tests of the functions here).  The functions below work on the
@@ -31,6 +34,7 @@ from __future__ import annotations
 
 import bisect
 import json
+import re
 import sys
 import warnings
 
@@ -173,8 +177,22 @@ def counter_metrics(c0: dict, c1: dict) -> dict:
         out["front.queue_wait_ms"] = 1e3 * d("queue_wait_s") / d("dequeued")
     if d("slab_elems") > 0:
         out["step.live_share"] = 100.0 * d("live_elems") / d("slab_elems")
+    if "banded_rows" in c1 and d("banded_rows") > 0:
+        out["step.packed_share"] = 100.0 * d("packed_rows") / d("banded_rows")
     out["jit.first_runs"] = d("first_runs")
     return out
+
+
+def ops_by_kind(ex: dict) -> list:
+    """[kind, seconds] of the window's device operations summed by kind:
+    the instruction name without `%`, its `.N` and its shape (`fusion`,
+    `sort`, a Pallas kernel's name such as `intersect_packed`)."""
+    tot: dict = {}
+    for name, sec in btrace.top_ops(ex, n=1 << 30):
+        kind = re.match(r"%?([A-Za-z_-]+)", name)
+        k = kind.group(1) if kind else name
+        tot[k] = tot.get(k, 0.0) + sec
+    return sorted(([k, v] for k, v in tot.items()), key=lambda r: -r[1])
 
 
 def trace_metrics(ex: dict) -> dict:
@@ -285,6 +303,8 @@ def report(ex: dict) -> dict:
             "spans_per_call": spans_per_call(ex),
             "throughput_qps": ex["throughput_qps"],
             "idle_share": btrace.idle_share(ex),
+            "device_ops": btrace.top_ops(ex),
+            "ops_by_kind": ops_by_kind(ex)[:12],
             "gc": {"collections": ex["c1"]["gc_collections"]
                    - ex["c0"]["gc_collections"],
                    "pause_s": ex["c1"]["gc_pause_s"]

@@ -78,8 +78,8 @@ from repro.core.postings import (BLOCK, PHRASE_BIAS, POS_BITS, concat_packed,
                                  pad_block_multiple)
 from repro.kernels.ops import (I32_SENTINEL, banded_delta_mask_rows,
                                banded_intersect_rows, banded_min_delta_rows,
-                               kword_window_hits, resolve_kernels,
-                               unpack_postings)
+                               kword_window_hits, packed_layout,
+                               resolve_kernels, unpack_postings)
 
 # table caps: a task exceeding these routes its whole plan to the flexible
 # executor (rare: >8 AND-groups or >8 unioned form fetches per slot).
@@ -509,10 +509,12 @@ class BatchExecutor:
             - self.dev.max_pos - max(self.dev.max_distance,
                                      self.dev.max_shift)
         # bucket-step calls: padded slab against live rows and gathered
-        # postings (elements), and calls of a step key not run before;
+        # postings (elements), banded rows and those the kernels pack, and
+        # calls of a step key not run before;
         # shard dispatchers may call one executor from several threads
         self.slab_stats = {"steps": 0, "slab_rows": 0, "live_rows": 0,
-                           "slab_elems": 0, "live_elems": 0, "first_runs": 0}
+                           "slab_elems": 0, "live_elems": 0, "first_runs": 0,
+                           "banded_rows": 0, "packed_rows": 0}
         self._ran: set = set()
         self._stats_lock = threading.Lock()
 
@@ -778,18 +780,24 @@ class BatchExecutor:
                                      presorted=sortfree, ranked=ranked,
                                      kword=kword)
 
-    def _count_slab(self, part: list, T: int, volume: int):
-        """Count one bucket-step call of `part` padded to T rows, each of
-        `volume` gathered postings (F*P0 + (G-1)*F*P)."""
+    def _count_slab(self, part: list, T: int, shape: tuple):
+        """Count one bucket-step call of `part` padded to T rows of bucket
+        `shape` (G, F, P0, P): each row gathers F*P0 + (G-1)*F*P postings
+        and runs G-1 banded rows of F*P0 seed keys against F*P constraint
+        keys, packed where `packed_layout` says the kernels pack them."""
+        G, F, P0, P = shape
         live = sum(ln for row in part for g in row.groups
                    for _, _, ln in g.slots)
+        banded = T * (G - 1)
         with self._stats_lock:
             st = self.slab_stats
             st["steps"] += 1
             st["slab_rows"] += T
             st["live_rows"] += len(part)
-            st["slab_elems"] += T * volume
+            st["slab_elems"] += T * (F * P0 + (G - 1) * F * P)
             st["live_elems"] += live
+            st["banded_rows"] += banded
+            st["packed_rows"] += banded if packed_layout(F * P0, F * P) else 0
 
     @staticmethod
     def _step_key(tj: dict, static: dict) -> tuple:
@@ -811,8 +819,7 @@ class BatchExecutor:
     def _run_rows(self, rows: list):
         for part, tj, static in self._bucket_chunks(rows):
             T, G, F = tj["start"].shape
-            self._count_slab(part, T, F * static["P0"]
-                             + (G - 1) * F * static["P"])
+            self._count_slab(part, T, (G, F, static["P0"], static["P"]))
             with self._first_run(self._step_key(tj, static)):
                 with obs.span("batch.step"):
                     out = _batch_step(self.dev.device_arena, tj, **static)

@@ -458,7 +458,7 @@ class _ServeBatchExecutor(BatchExecutor):
                     t["start"][m] = np.searchsorted(self._sel[dd],
                                                     t["start"][m])
                 t["owner"] = owner
-                self._count_slab(part, T, self._tier_volume((G, F, P0, Pc)))
+                self._count_slab(part, T, (G, F, P0, Pc))
                 tj = {k: jnp.asarray(v) for k, v in t.items()}
                 key = self._step_key(tj, dict(ranked=ranked, kword=kword,
                                               P0=P0, P=Pc))

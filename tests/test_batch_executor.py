@@ -405,21 +405,55 @@ def test_search_batch_segmented_shards_match(small_world, dps):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("N,Pa,Pb,seed", [(4, 256, 256, 0), (9, 512, 1024, 1),
-                                          (16, 256, 2048, 2), (1, 128, 128, 3)])
-def test_banded_intersect_rows_matches_ref(N, Pa, Pb, seed):
-    """Pallas vs ref on keys shaped like the executor's re-based int32 domain
-    (doc_local << 17 | pos), with mixed per-row bands and sentinel padding."""
+# packed-layout cases (seeds from 10): row counts that are not a multiple
+# of 8, widths up to one 1024-key tile on each side, and one wide pair that
+# keeps the tiled kernel; 70 rows of 1024 keys span three packed blocks
+PACKED_CASES = [(n, pa, pb, 10 + i) for i, (n, (pa, pb)) in enumerate(
+    (n, w) for n in (1, 7, 9, 13)
+    for w in ((128, 128), (128, 512), (384, 1024), (1024, 128),
+              (1024, 2048)))] + [(70, 1024, 1024, 30)]
+# one case per row count for the twins, cycling the widths
+TWIN_CASES = PACKED_CASES[::6] + [PACKED_CASES[-1]]
+
+
+def _rebased_rows(rng, N, Pa, Pb):
+    """Keys shaped like the executor's re-based int32 domain
+    (doc_local << 17 | pos): a [N, Pa], b [N, Pb], both unsorted."""
     from repro.core.fetch_tables import TABLE_BIAS, TABLE_POS_BITS
-    rng = np.random.default_rng(seed)
     doc_a = rng.integers(0, 50, (N, Pa))
     doc_b = rng.integers(0, 50, (N, Pb))
     pos_a = rng.integers(0, 400, (N, Pa))
     pos_b = rng.integers(0, 400, (N, Pb))
     a = ((doc_a << TABLE_POS_BITS) | (pos_a + TABLE_BIAS)).astype(np.int32)
-    b = np.sort((doc_b << TABLE_POS_BITS) | (pos_b + TABLE_BIAS), axis=1).astype(np.int32)
+    b = ((doc_b << TABLE_POS_BITS) | (pos_b + TABLE_BIAS)).astype(np.int32)
+    return a, b
+
+
+def _dead_rows(a, b_keys, seed):
+    """Rows without keys: the older cases blank the last row's b keys; the
+    packed cases (seeds from 10) every third row's from row 1, among live
+    rows of one block, and, past 9 rows, the a keys of the first half of the
+    rows rounded down to 8 (a whole block, where a block holds that many
+    rows: 70 rows of 1024 keys pack 32 to a block)."""
+    if seed < 10:
+        b_keys[-1] = np.iinfo(np.int32).max
+        return
+    b_keys[1::3] = np.iinfo(np.int32).max
+    if len(a) > 9:
+        a[:len(a) // 2 // 8 * 8] = np.iinfo(np.int32).max
+
+
+@pytest.mark.parametrize("N,Pa,Pb,seed", [(4, 256, 256, 0), (9, 512, 1024, 1),
+                                          (16, 256, 2048, 2), (1, 128, 128, 3)]
+                         + PACKED_CASES)
+def test_banded_intersect_rows_matches_ref(N, Pa, Pb, seed):
+    """Pallas vs ref on keys shaped like the executor's re-based int32 domain
+    (doc_local << 17 | pos), with mixed per-row bands and sentinel padding."""
+    rng = np.random.default_rng(seed)
+    a, b = _rebased_rows(rng, N, Pa, Pb)
+    b = np.sort(b, axis=1)
     a[:, -7:] = np.iinfo(np.int32).max            # sentinel pads
-    b[-1, :] = np.iinfo(np.int32).max             # one empty (dead) group
+    _dead_rows(a, b, seed)                    # empty (dead) groups
     bands = rng.integers(0, 6, N).astype(np.int32)
     got = ops.banded_intersect_rows(jnp.asarray(a), jnp.asarray(b),
                                     jnp.asarray(bands))
@@ -431,19 +465,13 @@ def test_banded_intersect_rows_matches_ref(N, Pa, Pb, seed):
 
 
 @pytest.mark.parametrize("N,Pa,Pb,seed", [(4, 256, 256, 0), (9, 512, 1024, 1),
-                                          (1, 128, 128, 3)])
+                                          (1, 128, 128, 3)] + TWIN_CASES)
 def test_banded_min_delta_rows_matches_ref(N, Pa, Pb, seed):
     """Pallas vs ref for the proximity-scoring kernel, on the valid domain:
     band-0 rows carry mixed stored deltas (dist-fetch groups), band>0 rows
     all-zero deltas (full-list groups) — rows sorted by (key, delta)."""
-    from repro.core.fetch_tables import TABLE_BIAS, TABLE_POS_BITS
     rng = np.random.default_rng(seed)
-    doc_a = rng.integers(0, 50, (N, Pa))
-    doc_b = rng.integers(0, 50, (N, Pb))
-    pos_a = rng.integers(0, 400, (N, Pa))
-    pos_b = rng.integers(0, 400, (N, Pb))
-    a = ((doc_a << TABLE_POS_BITS) | (pos_a + TABLE_BIAS)).astype(np.int32)
-    bk = ((doc_b << TABLE_POS_BITS) | (pos_b + TABLE_BIAS)).astype(np.int32)
+    a, bk = _rebased_rows(rng, N, Pa, Pb)
     bands = rng.integers(0, 6, N).astype(np.int32)
     bd = np.where(bands[:, None] == 0,
                   rng.integers(0, 16, (N, Pb)), 0).astype(np.int32)
@@ -451,7 +479,7 @@ def test_banded_min_delta_rows_matches_ref(N, Pa, Pb, seed):
     bk = np.take_along_axis(bk, order, axis=-1)
     bd = np.take_along_axis(bd, order, axis=-1)
     a[:, -5:] = np.iinfo(np.int32).max           # sentinel pads
-    bk[-1, :] = np.iinfo(np.int32).max           # one dead group
+    _dead_rows(a, bk, seed)                   # empty (dead) groups
     got = ops.banded_min_delta_rows(jnp.asarray(a), jnp.asarray(bk),
                                     jnp.asarray(bd), jnp.asarray(bands))
     want = ops.banded_min_delta_rows(jnp.asarray(a), jnp.asarray(bk),
@@ -467,12 +495,35 @@ def test_banded_min_delta_rows_matches_ref(N, Pa, Pb, seed):
     assert (np.asarray(got)[:, -5:] == np.iinfo(np.int32).max).all()
 
 
+@pytest.mark.parametrize("N,Pa,Pb,seed", TWIN_CASES)
+def test_banded_delta_mask_rows_matches_ref(N, Pa, Pb, seed):
+    """Pallas vs ref for the K-word delta-mask kernel: per-row windows up to
+    the device cap of 15, mixed within a block, over keys of three docs so
+    that masks hold offsets."""
+    rng = np.random.default_rng(seed)
+    a, b = _rebased_rows(rng, N, Pa, Pb)
+    a, b = a % (3 << 17), np.sort(b % (3 << 17), axis=1)
+    a[:, -3:] = np.iinfo(np.int32).max           # sentinel pads
+    _dead_rows(a, b, seed)                    # empty (dead) groups
+    bands = rng.integers(0, 16, N).astype(np.int32)
+    got = ops.banded_delta_mask_rows(jnp.asarray(a), jnp.asarray(b),
+                                     jnp.asarray(bands))
+    want = ops.banded_delta_mask_rows(jnp.asarray(a), jnp.asarray(b),
+                                      jnp.asarray(bands),
+                                      implementation="ref")
+    assert bool((got == want).all())
+    assert np.asarray(want).any()
+    assert not np.asarray(got)[:, -3:].any()
+
+
 def test_banded_intersect_rows_band_isolation():
-    """Rows with band 0 must not leak band-W semantics from neighbours."""
-    a = np.tile(np.arange(0, 1280, 10, np.int32), (2, 1))[:, :128]
-    b = np.tile((np.arange(0, 1280, 10, np.int32) + 3), (2, 1))[:, :128]
-    bands = np.array([0, 5], np.int32)
+    """Rows with band 0 must not leak band-W semantics from neighbours,
+    inside a packed block of eight rows and across two."""
+    n = 10
+    a = np.tile(np.arange(0, 1280, 10, np.int32), (n, 1))[:, :128]
+    b = np.tile((np.arange(0, 1280, 10, np.int32) + 3), (n, 1))[:, :128]
+    bands = np.array([0, 5] * (n // 2), np.int32)
     got = np.asarray(ops.banded_intersect_rows(jnp.asarray(a), jnp.asarray(b),
                                                jnp.asarray(bands)))
-    assert not got[0].any()       # off by 3, band 0 -> no hits
-    assert got[1].all()           # band 5 covers the offset
+    assert not got[0::2].any()    # off by 3, band 0 -> no hits
+    assert got[1::2].all()        # band 5 covers the offset

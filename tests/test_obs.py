@@ -149,7 +149,8 @@ def test_slab_stats_count_the_padded_tables(small_world):
     eng.search_batch(_requests(corpus, 12)
                      + _requests(corpus, 12, seed=5, mode=MODE_NEAR))
     want = {"steps": len(seen), "slab_rows": 0, "live_rows": 0,
-            "slab_elems": 0, "live_elems": 0}
+            "slab_elems": 0, "live_elems": 0, "banded_rows": 0,
+            "packed_rows": 0}
     for part, tj, static in seen:
         T, G, F = tj["start"].shape
         want["slab_rows"] += T
@@ -157,9 +158,46 @@ def test_slab_stats_count_the_padded_tables(small_world):
         want["slab_elems"] += T * (F * static["P0"]
                                    + (G - 1) * F * static["P"])
         want["live_elems"] += int(np.asarray(tj["length"]).sum())
+        want["banded_rows"] += T * (G - 1)
+        if max(F * static["P0"], F * static["P"]) <= 1024:
+            want["packed_rows"] += T * (G - 1)
     got = {k: v for k, v in be.slab_stats.items() if k != "first_runs"}
     assert got == want and want["steps"] > 0
     assert 0 < want["live_elems"] < want["slab_elems"]
+
+
+@pytest.mark.parametrize("floor", [None, 2048])
+def test_packed_rows_count_the_narrow_buckets(small_world, monkeypatch,
+                                              floor):
+    """`packed_rows` counts the banded rows (T * (G-1)) of the bucket steps
+    whose rows are at most 1024 keys wide on both sides: every row of the
+    default caps' narrow buckets, and none where a row floor of 2048 keys
+    widens every bucket past one tile."""
+    from repro.core import batch_executor
+    if floor is not None:
+        monkeypatch.setattr(batch_executor, "P_FLOOR", floor)
+    eng = AdditionalIndexEngine(small_world["index"])
+    be = eng.batch_executor
+    seen = _chunk_recorder(be)
+    reqs = (_requests(small_world["corpus"], 8)
+            + _requests(small_world["corpus"], 8, seed=5, mode=MODE_NEAR))
+    got = eng.search_batch(reqs)
+    narrow = 0
+    for _, tj, static in seen:
+        T, G, F = tj["start"].shape
+        if max(F * static["P0"], F * static["P"]) <= 1024:
+            narrow += T * (G - 1)
+    st = be.slab_stats
+    assert st["banded_rows"] > 0
+    assert st["packed_rows"] == narrow
+    if floor is None:
+        assert narrow > 0
+    else:
+        assert narrow == 0
+        ref = small_world["engine"].search_batch(reqs)
+        for x, y in zip(got, ref):
+            assert np.array_equal(x.doc, y.doc)
+            assert np.array_equal(x.pos, y.pos)
 
 
 def test_first_runs_count_new_step_keys_once(small_world):

@@ -12,6 +12,7 @@ module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
 import os
+import re
 from functools import partial
 
 import jax
@@ -64,6 +65,14 @@ def _kernel_calls(fn, *args) -> int:
         "tpu_custom_call")
 
 
+def _kernel_names(txt: str) -> list[str]:
+    """The names of a compiled program's Pallas kernels (the custom-call
+    instructions, without their `.N` suffix)."""
+    return [m.group(1) for m in re.finditer(
+        r"%([A-Za-z_]+)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        txt)]
+
+
 BANDED = {
     "intersect": lambda a, b, d, bands: ops.banded_intersect_rows(
         a, b, bands, implementation="pallas", interpret=False),
@@ -74,17 +83,23 @@ BANDED = {
 }
 
 
-@pytest.mark.parametrize("pa,pb", [(128, 128), (256, 2048), (2048, 8192)])
+@pytest.mark.parametrize("pa,pb", [(128, 128), (256, 2048), (2048, 8192),
+                                   (512, 1024), (1024, 128)])
 @pytest.mark.parametrize("kernel", sorted(BANDED))
 def test_banded_kernel_compiles_for_v5e(one_chip, kernel, pa, pb):
     """The three banded row kernels at served widths: seed rows F*P0 wide
-    against constraint rows F*P wide."""
+    against constraint rows F*P wide.  Rows of at most 1024 keys on both
+    sides run the packed kernel, wider ones the tiled kernel alone."""
     i32 = jnp.int32
     args = _on(one_chip, (jax.ShapeDtypeStruct((ROWS, pa), i32),
                           jax.ShapeDtypeStruct((ROWS, pb), i32),
                           jax.ShapeDtypeStruct((ROWS, pb), i32),
                           jax.ShapeDtypeStruct((ROWS,), i32)))
-    assert _kernel_calls(BANDED[kernel], *args) >= 1
+    names = _kernel_names(
+        jax.jit(BANDED[kernel]).lower(*args).compile().as_text())
+    packed = max(pa, pb) <= 1024
+    assert packed == ops.packed_layout(pa, pb)
+    assert names == [kernel + "_packed" if packed else kernel]
 
 
 @pytest.mark.parametrize("width", [128, 2048, 8192])
@@ -105,6 +120,9 @@ STEPS = {
                    kword=False),
     "phrase_wide": dict(shape=(128, 8, 8, 128, 2048, 4, 2), ranked=False,
                         kword=False),
+    # the benchmark cell's leading bucket: 128-key rows, packed kernel
+    "phrase_narrow": dict(shape=(2048, 4, 1, 128, 128, 4, 2), ranked=False,
+                          kword=False),
     "ranked": dict(shape=(ROWS, 4, 2, 256, 1024, 0, 0), ranked=True,
                    kword=False),
     "kword": dict(shape=(ROWS, 4, 1, 512, 4096, 0, 0), ranked=False,
@@ -133,5 +151,10 @@ def test_bucket_step_compiles_for_v5e(one_chip, kind):
         _on(one_chip, arena),
         _on(one_chip, batch_table_specs(T, G, F, C, M))).compile()
     # unpack for the seed and the constraint groups, plus the banded pass
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # in the layout the row widths select
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    banded = [n for n in _kernel_names(text) if n != "unpack"]
+    packed = ops.packed_layout(F * P0, F * P)
+    assert banded and all(n.endswith("_packed") == packed for n in banded)
     assert compiled.memory_analysis().temp_size_in_bytes < STEP_TEMP_LIMIT

@@ -70,6 +70,19 @@ def test_counter_metrics():
          "jit.first_runs": 0})
 
 
+def test_counter_metrics_packed_share():
+    """`step.packed_share` where the counters have banded rows; absent
+    where the program lacks the counter."""
+    c0 = dict(dequeued=0, queue_wait_s=0.0, slab_elems=10, live_elems=1,
+              first_runs=0, banded_rows=100, packed_rows=40)
+    c1 = dict(c0, slab_elems=20, live_elems=2, banded_rows=300,
+              packed_rows=220)
+    assert ts.counter_metrics(c0, c1)["step.packed_share"] == \
+        pytest.approx(90.0)
+    old = {k: v for k, v in c0.items() if "banded" not in k}
+    assert "step.packed_share" not in ts.counter_metrics(old, old)
+
+
 @pytest.fixture(scope="module")
 def rec():
     with open(FIXTURE) as f:
@@ -89,6 +102,14 @@ def test_idle_by_span_adds_up(rec):
     assert idle == pytest.approx(btrace.window_s(rec) - btrace.busy_s(rec),
                                  rel=1e-9)
     assert dict(rows).get("none", 0.0) <= 0.1 * idle
+
+
+def test_ops_by_kind_adds_up(rec):
+    kinds = dict(ts.ops_by_kind(rec))
+    total = sum(sec for _, sec in btrace.top_ops(rec, n=1 << 30))
+    assert sum(kinds.values()) == pytest.approx(total, rel=1e-9)
+    assert kinds["fusion"] > 0
+    assert not any("." in k or "%" in k for k in kinds)
 
 
 def test_recorded_metrics(rec):
